@@ -34,6 +34,9 @@ overhead), measuring the loss-vs-bytes frontier from *observed* traffic
     land as tallied per-round erasures (codec-level validation, not just
     CRC), server still exits 0.
 
+The fleet is a CPU simulation: every child runs with ``JAX_PLATFORMS=cpu``,
+so on a host with a TPU none of them takes the chip (one process per chip).
+
 Machine-readable results: ``benchmarks/out/BENCH_fleet_chaos.json`` and
 ``benchmarks/out/BENCH_fleet_comlad.json`` (validated in tier-1 by
 ``scripts/bench_smoke.py``; regenerated + uploaded by the CI ``fleet-chaos``
@@ -87,6 +90,7 @@ def _run_fleet(cfg, *, chaos: dict | None = None, extra_argv: list[str] = (),
     """One fleet run from a FleetConfig; returns (server RESULT, line, rcs)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU fleet: no process may take the chip
     children = []
     for pid in range(cfg.procs):
         c = dataclasses.replace(cfg, proc_id=pid)

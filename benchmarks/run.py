@@ -23,6 +23,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main() -> None:
     from benchmarks.paper_figures import FIGURES
+    from repro.compile_cache import enable_compile_cache
 
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
@@ -41,6 +42,7 @@ def main() -> None:
     if unknown:
         parser.error(f"unknown figures {unknown}; have {sorted(FIGURES)}")
 
+    enable_compile_cache()
     os.makedirs("benchmarks/out", exist_ok=True)
     print("name,us_per_call,derived")
 

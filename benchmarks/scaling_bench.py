@@ -11,12 +11,15 @@ in tier-1 by scripts/bench_smoke.py) with speedup-vs-1-device columns.
 
 Each row carries the roofline wiring next to the wall clock: the chunk
 program's optimized HLO (``scenarios.grid_compiled_hlo``) analyzed by
-``launch.roofline.analyze_compiled`` gives a predicted runtime at platform
-peaks, and ``pct_of_peak`` = predicted / measured — the relative-efficiency
-number ``scripts/perf_gate.py`` tracks across PRs alongside warm seconds.
+``launch.roofline.analyze_compiled`` gives a predicted runtime at the
+device's peaks, and ``pct_of_peak`` = predicted / measured — the
+relative-efficiency number ``scripts/perf_gate.py`` tracks across PRs
+alongside warm seconds.
 
-Forced host devices share the same physical cores, so on a small CI box the
-*absolute* speedups hover near 1; what the curve certifies is that sharding
+Every point is a CPU simulation: the children run with ``JAX_PLATFORMS=cpu``
+even on a host with a TPU (one process per chip), and the parent never
+touches jax.  Forced host devices share the same physical cores, so on a
+small CI box the *absolute* speedups hover near 1; what the curve certifies is that sharding
 never falls off a cliff (monotonicity within tolerance) and that warm time
 does not regress vs the committed baseline — see scripts/perf_gate.py.
 
@@ -109,8 +112,13 @@ def scaling_row(
 
 
 def _child_env(n_devices: int) -> dict:
-    """Subprocess env forcing ``n_devices`` host devices before jax init."""
+    """Subprocess env forcing ``n_devices`` host devices before jax init.
+
+    The children are a CPU simulation (forced host devices), so they run with
+    ``JAX_PLATFORMS=cpu``: on a host with a TPU they must not contend for the
+    chip, which only one process may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [
         f for f in env.get("XLA_FLAGS", "").split()
         if not f.startswith("--xla_force_host_platform_device_count")

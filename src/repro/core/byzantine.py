@@ -162,7 +162,28 @@ def _device_coded_gradients(cfg: ProtocolConfig, key: jax.Array, subset_grads: j
             subsets,
             assign,
         )
-    return _encode(cfg, subset_grads[subsets]), subsets, assign
+    return _encode(cfg, _gather_rows(subset_grads, subsets)), subsets, assign
+
+
+# Row length from which ``_gather_rows`` slices instead of gathering.
+_LONG_ROW = 1 << 16
+
+
+def _gather_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for an ``(N, Q)`` stack and an ``(N, d)`` index table.
+
+    XLA:TPU compiles a gather of whole rows in time that grows with the row
+    length (30 s at ``Q = 2^25``; minutes inside an LM train step), while
+    ``N * d`` unrolled dynamic row slices compile in about a second.  The
+    unrolled form costs compile time in ``N * d`` instead, so short rows
+    (the linear-regression grids: ``Q ~ 100``, ``N * d`` up to thousands)
+    keep the gather.  Both give the same rows bit for bit."""
+    if x.shape[-1] < _LONG_ROW:
+        return x[idx]
+    return jnp.stack([
+        jnp.stack([jax.lax.dynamic_index_in_dim(x, i, keepdims=False) for i in row])
+        for row in idx
+    ])
 
 
 def _full_server_fn(cfg: ProtocolConfig) -> Callable[[jax.Array], jax.Array]:

@@ -36,7 +36,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.byzantine import (
@@ -670,7 +669,7 @@ def run_grid(
         * ``"none"``      — single-device vmap (the default; exactly the
           pre-sharding path);
         * ``"shard_map"``  — the lane axis is partitioned over every visible
-          device with ``jax.experimental.shard_map`` (each device runs the
+          device with ``jax.shard_map`` (each device runs the
           identical vmapped scan on its lane shard; one jitted program);
         * ``"pmap"``      — the same partition via ``jax.pmap`` (per-device
           replica dispatch; kept as the second substrate / cross-check).
@@ -1040,16 +1039,16 @@ def _grid_program(
             PartitionSpec("lanes") if ax == 0 else PartitionSpec()
             for ax in in_axes
         )
-        # check_rep off: every output is lane-partitioned, there is nothing
+        # check_vma off: every output is lane-partitioned, there is nothing
         # replicated for the static checker to prove — and the checker has no
         # rules for some of the primitives the round body uses
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 vmapped,
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=PartitionSpec("lanes"),
-                check_rep=False,
+                check_vma=False,
             )
         )
 

@@ -40,27 +40,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ref import _honest_stats_ref
+from repro.kernels.tiling import lane_row, lane_rows, row_tiles
 from repro.numerics import tree_sum
 
 
 def _sign_flip_kernel(msgs_ref, mask_ref, out_ref, *, coeff: float):
     m = msgs_ref[0]  # (N, q_block)
-    mask = mask_ref[0]  # (N,)
+    mask = mask_ref[0, 0]  # (N,)
     out_ref[0] = jnp.where(mask[:, None] > 0, coeff * m, m).astype(out_ref.dtype)
 
 
 def _alie_kernel(msgs_ref, mask_ref, mu_ref, var_ref, out_ref, *, z: float):
     m = msgs_ref[0].astype(jnp.float32)
-    mask = mask_ref[0]  # (N,)
-    adv = mu_ref[0] - z * jnp.sqrt(var_ref[0] + 1e-12)  # (q_block,)
-    out_ref[0] = jnp.where(mask[:, None] > 0, adv[None, :], m).astype(out_ref.dtype)
+    mask = mask_ref[0, 0]  # (N,)
+    adv = mu_ref[0] - z * jnp.sqrt(var_ref[0] + 1e-12)  # (1, q_block)
+    out_ref[0] = jnp.where(mask[:, None] > 0, adv, m).astype(out_ref.dtype)
 
 
 def _ipm_kernel(msgs_ref, mask_ref, mu_ref, out_ref, *, eps: float):
     m = msgs_ref[0].astype(jnp.float32)
-    mask = mask_ref[0]  # (N,)
-    adv = -eps * mu_ref[0]  # (q_block,)
-    out_ref[0] = jnp.where(mask[:, None] > 0, adv[None, :], m).astype(out_ref.dtype)
+    mask = mask_ref[0, 0]  # (N,)
+    adv = -eps * mu_ref[0]  # (1, q_block)
+    out_ref[0] = jnp.where(mask[:, None] > 0, adv, m).astype(out_ref.dtype)
 
 
 def _stat_operands(msgs: jax.Array, mask: jax.Array, name: str):
@@ -113,30 +114,16 @@ def attack_pallas_lanes(
     q_block = min(q_block, q)
     assert q % q_block == 0, (q, q_block)
     stats = _stat_operands(msgs, mask, name)
-    stat_spec = pl.BlockSpec((1, q_block), lambda l, i: (l, i))
     return pl.pallas_call(
         functools.partial(kernel, **{pname: param}),
         grid=(lanes, q // q_block),
         in_specs=[
             pl.BlockSpec((1, n, q_block), lambda l, i: (l, 0, i)),
-            pl.BlockSpec((1, n), lambda l, i: (l, 0)),
+            lane_row(n),
         ]
-        + [stat_spec] * len(stats),
+        + [row_tiles(q_block)] * len(stats),
         out_specs=pl.BlockSpec((1, n, q_block), lambda l, i: (l, 0, i)),
         out_shape=jax.ShapeDtypeStruct((lanes, n, q), msgs.dtype),
         interpret=interpret,
-    )(msgs, mask, *stats)
+    )(msgs, lane_rows(mask), *map(lane_rows, stats))
 
-
-def attack_pallas(
-    msgs: jax.Array,
-    mask: jax.Array,
-    name: str,
-    param: float,
-    q_block: int = 2048,
-    interpret: bool = True,
-) -> jax.Array:
-    """msgs: (N, Q), mask: (N,) -> (N, Q) — the L=1 lane."""
-    return attack_pallas_lanes(
-        msgs[None], mask[None], name, param, q_block=q_block, interpret=interpret
-    )[0]

@@ -27,43 +27,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.numerics import tree_sum
+from repro.kernels.tiling import row_tiles
+from repro.numerics import tree_sum_rows
 
 
-def _sort_rows(x: jax.Array) -> jax.Array:
-    """Odd-even transposition sort along axis 0 (static, branch-free)."""
-    n = x.shape[0]
+def _sort_rows(rows: list) -> list:
+    """Odd-even transposition sort of a list of equal-shape rows: ``n``
+    passes of compare-exchanges between neighbouring rows (static,
+    branch-free; each exchange is a vectorized min/max on the VPU)."""
+    rows = list(rows)
+    n = len(rows)
     for phase in range(n):
-        start = phase % 2
-        # pairs (start, start+1), (start+2, start+3), ...
-        a = x[start::2]
-        b = x[start + 1 :: 2]
-        k = min(a.shape[0], b.shape[0])
-        if k == 0:  # odd phase of a 2-row tile: nothing to exchange
-            continue
-        lo = jnp.minimum(a[:k], b[:k])
-        hi = jnp.maximum(a[:k], b[:k])
-        inter = jnp.stack([lo, hi], axis=1).reshape(2 * k, -1)
-        parts = []
-        if start:
-            parts.append(x[:1])
-        parts.append(inter)
-        tail = start + 2 * k
-        if tail < n:
-            parts.append(x[tail:])
-        x = jnp.concatenate(parts, axis=0)
-    return x
+        for i in range(phase % 2, n - 1, 2):
+            a, b = rows[i], rows[i + 1]
+            rows[i], rows[i + 1] = jnp.minimum(a, b), jnp.maximum(a, b)
+    return rows
 
 
 def _cwtm_kernel(msgs_ref, out_ref, *, trim: int):
-    x = msgs_ref[0]  # (N, q_block): this lane's tile
-    n = x.shape[0]
-    srt = _sort_rows(x.astype(jnp.float32))
-    kept = srt[trim : n - trim] if trim > 0 else srt
+    n = msgs_ref.shape[1]
+    # this lane's (N, q_block) tile as N static single-row (1, q_block) loads
+    rows = [msgs_ref[0, r : r + 1, :].astype(jnp.float32) for r in range(n)]
+    kept = _sort_rows(rows)[trim : n - trim]
     # fixed-tree mean, not jnp.mean: a reduce op may accumulate in a
     # different order per program shape, breaking the engine's cross-mode
     # bitwise guarantee (see repro/numerics.py)
-    mean = tree_sum(kept, axis=0) * jnp.float32(1.0 / kept.shape[0])
+    mean = tree_sum_rows(kept) * jnp.float32(1.0 / len(kept))
     out_ref[0] = mean.astype(out_ref.dtype)
 
 
@@ -77,20 +66,12 @@ def cwtm_pallas_lanes(
         raise ValueError(f"trim={trim} too large for N={n}")
     q_block = min(q_block, q)
     assert q % q_block == 0, (q, q_block)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_cwtm_kernel, trim=trim),
         grid=(lanes, q // q_block),
         in_specs=[pl.BlockSpec((1, n, q_block), lambda l, i: (l, 0, i))],
-        out_specs=pl.BlockSpec((1, q_block), lambda l, i: (l, i)),
-        out_shape=jax.ShapeDtypeStruct((lanes, q), msgs.dtype),
+        out_specs=row_tiles(q_block),
+        out_shape=jax.ShapeDtypeStruct((lanes, 1, q), msgs.dtype),
         interpret=interpret,
     )(msgs)
-
-
-def cwtm_pallas(
-    msgs: jax.Array, trim: int, q_block: int = 2048, interpret: bool = True
-) -> jax.Array:
-    """msgs: (N, Q) -> (Q,) trimmed mean — the L=1 lane of the batched grid."""
-    return cwtm_pallas_lanes(
-        msgs[None], trim, q_block=q_block, interpret=interpret
-    )[0]
+    return out[:, 0]

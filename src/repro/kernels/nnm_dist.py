@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import lane_row
 from repro.numerics import tree_sum
 
 
@@ -35,7 +36,7 @@ def _gram_kernel(msgs_ref, gram_ref, sq_ref):
     # fixed-tree row norms: a reduce op may accumulate in a different order
     # per program shape (see repro/numerics.py); the Gram matmul is a
     # dot_general with a fixed per-shape lowering
-    sq_ref[0] += tree_sum(x * x, axis=1)
+    sq_ref[0, 0] += tree_sum(x * x, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("q_block", "interpret"))
@@ -44,23 +45,18 @@ def gram_pallas_lanes(msgs: jax.Array, q_block: int = 2048, interpret: bool = Tr
     lanes, n, q = msgs.shape
     q_block = min(q_block, q)
     assert q % q_block == 0, (q, q_block)
-    return pl.pallas_call(
+    gram, sq = pl.pallas_call(
         _gram_kernel,
         grid=(lanes, q // q_block),
         in_specs=[pl.BlockSpec((1, n, q_block), lambda l, i: (l, 0, i))],
         out_specs=[
             pl.BlockSpec((1, n, n), lambda l, i: (l, 0, 0)),
-            pl.BlockSpec((1, n), lambda l, i: (l, 0)),
+            lane_row(n),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((lanes, n, n), jnp.float32),
-            jax.ShapeDtypeStruct((lanes, n), jnp.float32),
+            jax.ShapeDtypeStruct((lanes, 1, n), jnp.float32),
         ],
         interpret=interpret,
     )(msgs)
-
-
-def gram_pallas(msgs: jax.Array, q_block: int = 2048, interpret: bool = True):
-    """msgs: (N, Q) -> (gram (N, N), sqnorms (N,)) — the L=1 lane."""
-    gram, sq = gram_pallas_lanes(msgs[None], q_block=q_block, interpret=interpret)
-    return gram[0], sq[0]
+    return gram, sq[:, 0]

@@ -18,9 +18,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import lane_rows, row_tiles
+
 
 def _quant_kernel(g_ref, u_ref, out_ref, *, levels: int):
-    g = g_ref[0].astype(jnp.float32)  # (q_block,): one lane's block
+    g = g_ref[0].astype(jnp.float32)  # (1, q_block): one lane's block
     u = u_ref[0]
     scale = jnp.max(jnp.abs(g))
     safe = jnp.where(scale > 0, scale, 1.0)
@@ -40,23 +42,12 @@ def stochastic_quantize_pallas_lanes(
     assert u.shape == g.shape, (u.shape, g.shape)
     q_block = min(q_block, q)
     assert q % q_block == 0, (q, q_block)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_quant_kernel, levels=levels),
         grid=(lanes, q // q_block),
-        in_specs=[
-            pl.BlockSpec((1, q_block), lambda l, i: (l, i)),
-            pl.BlockSpec((1, q_block), lambda l, i: (l, i)),
-        ],
-        out_specs=pl.BlockSpec((1, q_block), lambda l, i: (l, i)),
-        out_shape=jax.ShapeDtypeStruct((lanes, q), g.dtype),
+        in_specs=[row_tiles(q_block), row_tiles(q_block)],
+        out_specs=row_tiles(q_block),
+        out_shape=jax.ShapeDtypeStruct((lanes, 1, q), g.dtype),
         interpret=interpret,
-    )(g, u)
-
-
-def stochastic_quantize_pallas(
-    g: jax.Array, u: jax.Array, levels: int = 16, q_block: int = 1024, interpret: bool = True
-) -> jax.Array:
-    """g, u: (Q,) -> (Q,) — the L=1 lane of the batched grid."""
-    return stochastic_quantize_pallas_lanes(
-        g[None], u[None], levels, q_block=q_block, interpret=interpret
-    )[0]
+    )(lane_rows(g), lane_rows(u))
+    return out[:, 0]

@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.numerics import tree_sum
+from repro.numerics import tree_sum, tree_sum_rows
 
 
 def cwtm_ref(msgs: jax.Array, trim: int) -> jax.Array:
@@ -21,20 +21,30 @@ def cwtm_ref(msgs: jax.Array, trim: int) -> jax.Array:
     return jnp.mean(kept.astype(jnp.float32), axis=-2).astype(msgs.dtype)
 
 
+@jax.jit
+def _weighted_row_sum(rows: jax.Array, weights: jax.Array) -> jax.Array:
+    """``sum_k weights[..., k] * rows[..., k, :]`` as the combine kernels
+    compute it: one weighted row per term, summed in the fixed tree of
+    ``tree_sum_rows``.  Jitted, so XLA fuses the multiply-adds as it does in
+    the kernels' interpret-mode program (op-by-op execution would round
+    every product, a fused program may not)."""
+    terms = [
+        rows[..., k, :].astype(jnp.float32) * weights[..., k, None].astype(jnp.float32)
+        for k in range(rows.shape[-2])
+    ]
+    return tree_sum_rows(terms).astype(rows.dtype)
+
+
 def coded_combine_ref(grads: jax.Array, weights: jax.Array) -> jax.Array:
     """eq.-(5) weighted combine.  grads: (..., d, Q), weights: (d,) or
     (..., d) -> (..., Q)."""
-    return jnp.einsum(
-        "...dq,...d->...q", grads.astype(jnp.float32), weights.astype(jnp.float32)
-    ).astype(grads.dtype)
+    return _weighted_row_sum(grads, jnp.broadcast_to(weights, grads.shape[:-1]))
 
 
 def masked_combine_ref(msgs: jax.Array, weights: jax.Array) -> jax.Array:
     """Weighted row-combine over the device axis (the erasure decode's
     surviving-class sum).  msgs: (..., N, Q), weights: (..., N) -> (..., Q)."""
-    return jnp.einsum(
-        "...nq,...n->...q", msgs.astype(jnp.float32), weights.astype(jnp.float32)
-    ).astype(msgs.dtype)
+    return _weighted_row_sum(msgs, weights)
 
 
 def stochastic_quantize_ref(
@@ -68,13 +78,7 @@ def gather_combine_ref(
     gathered = jnp.take_along_axis(
         grads[..., None, :], subsets[..., :, :, None], axis=-3
     )  # (..., N, d, Q)
-    return jnp.einsum(
-        "...ndq,...d->...nq",
-        gathered.astype(jnp.float32),
-        jnp.broadcast_to(weights, subsets.shape[:-2] + weights.shape[-1:]).astype(
-            jnp.float32
-        ),
-    ).astype(grads.dtype)
+    return _weighted_row_sum(gathered, jnp.broadcast_to(weights, subsets.shape))
 
 
 def _honest_stats_ref(msgs: jax.Array, mask: jax.Array):

@@ -35,6 +35,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.engine import (  # noqa: F401  (re-exported deployment API)
     engine_device_count,
@@ -44,10 +45,17 @@ from repro.core.engine import (  # noqa: F401  (re-exported deployment API)
 )
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the GSPMD train path relies
+    on sharding propagation, which the default ``Explicit`` axis types turn
+    into per-op ``out_sharding`` requirements (e.g. on the embedding gather)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int | None = None):
@@ -57,8 +65,8 @@ def make_host_mesh(data: int = 2, model: int = 2, pod: int | None = None):
     if want > n:
         raise ValueError(f"mesh {want} > available devices {n}")
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

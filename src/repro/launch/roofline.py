@@ -394,41 +394,51 @@ def derive_terms(
 # ---------------------------------------------------------------------------
 # %-of-peak for engine programs (the benchmark-row wiring)
 # ---------------------------------------------------------------------------
-# The dry-run path above targets the TPU v5e constants; the scaling benches
-# run the engine's compiled programs on whatever backend is live, so the
-# roofline needs per-platform peaks.  The CPU numbers are order-of-magnitude
-# figures for one commodity core (a few GFLOP/s of non-vectorized f32 work,
-# ~10 GB/s effective stream bandwidth) — good enough to TRACK "% of peak"
-# across PRs on the same CI runner class, not to compare machines.
+# The dry-run path above targets the TPU v5e constants; the engine benches
+# analyze compiled programs for whatever device runs them, so the roofline
+# keys its peaks by ``jax.devices()[0].device_kind``.  A device missing here
+# is an error, never a default.
+#
+#   "TPU v5 lite" (TPU v5e): Google Cloud documentation, "TPU v5e" —
+#       197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI (4 links x 50 GB/s).
+#   "cpu": NOT a device peak.  A CPU-only relative figure (one commodity
+#       core: a few GFLOP/s of f32, ~10 GB/s stream bandwidth), kept only so
+#       the CPU scaling bench (benchmarks/scaling_bench.py) can track its
+#       own "% of peak" across runs on one runner class.
 
-PLATFORM_PEAKS = {
-    "tpu": {"peak_flops": PEAK_FLOPS, "mem_bw": HBM_BW, "link_bw": LINK_BW},
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops": PEAK_FLOPS, "mem_bw": HBM_BW, "link_bw": LINK_BW},
     "cpu": {"peak_flops": 8e9, "mem_bw": 10e9, "link_bw": 10e9},
 }
 
 
-def platform_peaks(platform: str | None = None) -> dict:
-    """{peak_flops, mem_bw, link_bw} for ``platform`` (default: the live jax
-    backend).  Unknown platforms (gpu today) fall back to the cpu figures —
-    pessimistic, clearly wrong in absolute terms, still monotone for
-    regression tracking."""
-    if platform is None:
+def device_peaks(device_kind: str | None = None) -> dict:
+    """{peak_flops, mem_bw, link_bw} for ``device_kind`` (default: the kind
+    of the first live jax device).  Raises for a kind with no recorded
+    peaks."""
+    if device_kind is None:
         import jax
 
-        platform = jax.default_backend()
-    return PLATFORM_PEAKS.get(platform, PLATFORM_PEAKS["cpu"])
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for device kind {device_kind!r}: add its "
+            "published figures, with their source, to DEVICE_PEAKS"
+        ) from None
 
 
-def analyze_compiled(hlo_text: str, platform: str | None = None) -> dict:
+def analyze_compiled(hlo_text: str, device_kind: str | None = None) -> dict:
     """Scan-aware cost of one compiled module + its roofline-predicted
-    runtime on ``platform``: ``{flops, bytes_hbm, wire_bytes, n_while,
+    runtime on ``device_kind`` (see ``device_peaks``): ``{flops, bytes_hbm, wire_bytes, n_while,
     max_trip, predicted_s, compute_s, memory_s, collective_s, dominant}``.
 
     ``predicted_s`` is the max of the three terms — the time a perfectly
     overlapped execution at peak rates would need.
     """
     an = analyze_hlo(hlo_text)
-    peaks = platform_peaks(platform)
+    peaks = device_peaks(device_kind)
     compute_s = an.flops / peaks["peak_flops"]
     memory_s = an.bytes_hbm / peaks["mem_bw"]
     collective_s = an.wire_bytes / peaks["link_bw"]
@@ -454,12 +464,12 @@ def percent_of_peak(
     """Roofline utilization of a measured wall clock: 100 x predicted / actual
     for ``calls`` executions of the analyzed module.
 
-    100 means the run hit the platform's roofline (never in practice; the
+    100 means the run hit the device's roofline (never in practice; the
     peaks are marketing numbers and the analysis undercounts overheads);
     the value is a *relative* efficiency tracked across PRs — a warm sweep
     whose %-of-peak halves got slower in a way wall clock alone can't
     attribute.  Clamped below at 0; not clamped above (a >100 reading means
-    the platform peaks in ``PLATFORM_PEAKS`` are stale for this machine —
+    the peaks in ``DEVICE_PEAKS`` are stale for this machine —
     visible is better than silently capped).
     """
     if measured_s <= 0:

@@ -51,7 +51,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import models
@@ -211,14 +210,14 @@ def _build_round_program(cfg, pcfg, remat, n_sub, shard, devs, specs):
                         gather(stack), k)
 
     if shard == "shard_map":
-        inner = shard_map(
+        inner = jax.shard_map(
             per_device,
             mesh=make_engine_mesh(_SUBSET_AXIS),
             in_specs=(P(), P(_SUBSET_AXIS), P()),
             out_specs=(P(), P(), P()),
             # every output is replicated by construction (post-all-gather);
-            # check_rep has no rules for some round-body primitives
-            check_rep=False,
+            # check_vma has no rules for some round-body primitives
+            check_vma=False,
         )
 
         @jax.jit

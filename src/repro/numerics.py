@@ -40,7 +40,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["tree_sum", "stable_norm", "stable_mean0", "stable_masked_mean0"]
+__all__ = ["tree_sum", "tree_sum_rows", "stable_norm", "stable_mean0", "stable_masked_mean0"]
 
 
 def _pad_pow2(v: jax.Array, axis: int) -> jax.Array:
@@ -68,6 +68,20 @@ def tree_sum(v: jax.Array, axis: int = -1) -> jax.Array:
         hi = jax.lax.slice_in_dim(v, h, 2 * h, axis=axis)
         v = lo + hi
     return jax.lax.squeeze(v, (axis,))
+
+
+def tree_sum_rows(rows) -> jax.Array:
+    """``tree_sum(jnp.stack(rows), axis=0)`` over a list of equal-shape
+    arrays, with the same adds in the same tree (zero-padded to a power of
+    two).  For kernel bodies, which keep rows as separate values because
+    Mosaic cannot slice a stacked tile at every row offset."""
+    rows = list(rows)
+    p = 1 << max(0, len(rows) - 1).bit_length()
+    rows += [jnp.zeros_like(rows[0])] * (p - len(rows))
+    while len(rows) > 1:
+        h = len(rows) // 2
+        rows = [rows[i] + rows[i + h] for i in range(h)]
+    return rows[0]
 
 
 def stable_norm(v: jax.Array) -> jax.Array:
