@@ -349,49 +349,55 @@ def protocol_round(
         )
     k_assign, k_mask, k_attack, k_comp = jax.random.split(key, 4)
 
-    coded, _, assign = _device_coded_gradients(cfg, k_assign, subset_grads)
+    # each stage runs under a ``lad.*`` named scope: HLO ``op_name`` metadata
+    # only, so a device trace can attribute the round's time by stage
+    with jax.named_scope("lad.encode"):
+        coded, _, assign = _device_coded_gradients(cfg, k_assign, subset_grads)
 
     # --- Com-LAD compression (Definition 2) --------------------------------
     q = coded.shape[1]
     spec = cfg.compression
     if spec.name not in ("none", "identity"):
-        if spec.name == "quant" and cfg.backend != "xla":
-            # kernel hot path: the rounding randomness u is drawn per device
-            # from its round key and fed to the fused quantize kernel — one
-            # lane-batched launch over the device axis
-            dev_keys = jax.random.split(k_comp, n)
-            u = jax.vmap(lambda k: jax.random.uniform(k, (q,)))(dev_keys)
-            coded = kernel_ops.stochastic_quantize(
-                coded, u, spec.levels, spec.chunk, backend=cfg.backend
-            )
-        else:
-            # single compression stage shared with the fleet's workers
-            # (compress_rows slices the same per-device key fan-out), so
-            # worker-side compression is bit-identical to this path
-            coded = comp_lib.compress_rows(spec, k_comp, coded, n_total=n)
+        with jax.named_scope("lad.compress"):
+            if spec.name == "quant" and cfg.backend != "xla":
+                # kernel hot path: the rounding randomness u is drawn per device
+                # from its round key and fed to the fused quantize kernel — one
+                # lane-batched launch over the device axis
+                dev_keys = jax.random.split(k_comp, n)
+                u = jax.vmap(lambda k: jax.random.uniform(k, (q,)))(dev_keys)
+                coded = kernel_ops.stochastic_quantize(
+                    coded, u, spec.levels, spec.chunk, backend=cfg.backend
+                )
+            else:
+                # single compression stage shared with the fleet's workers
+                # (compress_rows slices the same per-device key fan-out), so
+                # worker-side compression is bit-identical to this path
+                coded = comp_lib.compress_rows(spec, k_comp, coded, n_total=n)
 
     # --- Byzantine corruption ----------------------------------------------
-    mask = attack_lib.sample_byzantine_mask(
-        k_mask, n, cfg.n_byz, fixed=cfg.attack.fixed_identity
-    )
-    attack = attack_fn if attack_fn is not None else make_attack_fn(cfg)
-    transmitted = attack(k_attack, coded, mask)
+    with jax.named_scope("lad.attack"):
+        mask = attack_lib.sample_byzantine_mask(
+            k_mask, n, cfg.n_byz, fixed=cfg.attack.fixed_identity
+        )
+        attack = attack_fn if attack_fn is not None else make_attack_fn(cfg)
+        transmitted = attack(k_attack, coded, mask)
 
     # --- Server aggregation ------------------------------------------------
     # (For DRACO the server is the majority-vote decoder; it ignores
     # compression — incompatible, per Section VII.B.)
     server = server_fn if server_fn is not None else make_server_fn(cfg)
-    if cfg.participation.active:
-        # --- Participation erasure (after the attack, before the server) ---
-        pm = (
-            participation_mask
-            if participation_mask is not None
-            else jnp.ones((n,), jnp.float32)
-        )
-        # erased rows become exact 0.0 (x * 1.0 is bitwise-exact on the rest)
-        transmitted = transmitted * pm[:, None]
-        return server(transmitted, pm, assign)
-    return server(transmitted)
+    with jax.named_scope("lad.aggregate"):
+        if cfg.participation.active:
+            # --- Participation erasure (after the attack, before the server) ---
+            pm = (
+                participation_mask
+                if participation_mask is not None
+                else jnp.ones((n,), jnp.float32)
+            )
+            # erased rows become exact 0.0 (x * 1.0 is bitwise-exact on the rest)
+            transmitted = transmitted * pm[:, None]
+            return server(transmitted, pm, assign)
+        return server(transmitted)
 
 
 def coded_draco_decode(

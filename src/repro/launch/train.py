@@ -175,8 +175,10 @@ def _build_round_program(cfg, pcfg, remat, n_sub, shard, devs, specs):
         def loss_fn(pp):
             return models.loss_fn(pp, specs, cfg, sub_batch, remat=remat)
 
-        (loss, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        flat, _ = flatten_pytree(jax.tree.map(lambda a: a.astype(jnp.float32), g))
+        with jax.named_scope("lad.fanout"):
+            (loss, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        with jax.named_scope("lad.flatten"):
+            flat, _ = flatten_pytree(jax.tree.map(lambda a: a.astype(jnp.float32), g))
         return loss, metrics, flat
 
     def finalize(losses, metricses, stack, k):
@@ -206,8 +208,10 @@ def _build_round_program(cfg, pcfg, remat, n_sub, shard, devs, specs):
         def gather(v):  # (local, ...) -> (N, ...): padding subsets sliced off
             return jax.lax.all_gather(v, _SUBSET_AXIS, tiled=True)[:n_sub]
 
-        return finalize(gather(losses), jax.tree.map(gather, metricses),
-                        gather(stack), k)
+        with jax.named_scope("lad.gather"):
+            losses, metricses, stack = (gather(losses), jax.tree.map(gather, metricses),
+                                        gather(stack))
+        return finalize(losses, metricses, stack, k)
 
     if shard == "shard_map":
         inner = jax.shard_map(
@@ -278,11 +282,13 @@ def _engine_apply_program(tcfg):
         @jax.jit
         def apply(params, opt_state, g_flat, step_idx):
             _ENGINE_TRACES["apply"] += 1  # runs at trace time only
-            _, flat_spec = flatten_pytree(params)
-            grads = unflatten_pytree(g_flat, flat_spec)
-            lr = schedule(step_idx)
-            return opt.update(params, grads, opt_state, lr,
-                              weight_decay=tcfg.weight_decay)
+            with jax.named_scope("lad.unflatten"):
+                _, flat_spec = flatten_pytree(params)
+                grads = unflatten_pytree(g_flat, flat_spec)
+            with jax.named_scope("lad.optimizer"):
+                lr = schedule(step_idx)
+                return opt.update(params, grads, opt_state, lr,
+                                  weight_decay=tcfg.weight_decay)
 
         prog = apply
         _ENGINE_PROGRAMS[key] = prog
@@ -358,41 +364,45 @@ def build_engine_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
             return tree
 
     def step(params, opt_state, batch, step_idx):
-        round_key = jax.random.fold_in(base_key, step_idx)
-
         def blocked(x):  # (B, ...) -> (N, B/N, ...)
             assert x.shape[0] % n_sub == 0, (x.shape, n_sub)
             return x.reshape((n_sub, x.shape[0] // n_sub) + x.shape[1:])
 
-        params = to_engine(params)
-        opt_state = to_engine(opt_state)
-        blocks = to_engine(jax.tree.map(blocked, batch))
-        if m <= 1:
-            loss, metrics, g_flat = round_prog(
-                params, blocks, jax.random.fold_in(round_key, 0)
-            )
-        else:
-            rows = jax.tree.leaves(blocks)[0].shape[1]
-            assert rows % m == 0, (rows, m)
-            sl = rows // m
-            per = [
-                round_prog(
-                    params,
-                    jax.tree.map(lambda x: x[:, j * sl : (j + 1) * sl], blocks),
-                    jax.random.fold_in(round_key, j),
+        # host spans on the profiler's clock (inactive TraceMes when no trace
+        # is being taken): what the host does while the device may wait
+        with jax.profiler.TraceAnnotation("lad.place"):
+            params = to_engine(params)
+            opt_state = to_engine(opt_state)
+            blocks = to_engine(jax.tree.map(blocked, batch))
+        with jax.profiler.TraceAnnotation("lad.dispatch_round"):
+            round_key = jax.random.fold_in(base_key, step_idx)
+            if m <= 1:
+                loss, metrics, g_flat = round_prog(
+                    params, blocks, jax.random.fold_in(round_key, 0)
                 )
-                for j in range(m)
-            ]
-            g_flat = per[0][2]
-            for _, _, g in per[1:]:  # fp32 accumulation, in microbatch order
-                g_flat = g_flat + g
-            g_flat = g_flat / m
-            loss = stable_mean0(jnp.stack([l for l, _, _ in per]))
-            metrics = jax.tree.map(
-                lambda *vs: stable_mean0(jnp.stack(vs)), *[met for _, met, _ in per]
-            )
+            else:
+                rows = jax.tree.leaves(blocks)[0].shape[1]
+                assert rows % m == 0, (rows, m)
+                sl = rows // m
+                per = [
+                    round_prog(
+                        params,
+                        jax.tree.map(lambda x: x[:, j * sl : (j + 1) * sl], blocks),
+                        jax.random.fold_in(round_key, j),
+                    )
+                    for j in range(m)
+                ]
+                g_flat = per[0][2]
+                for _, _, g in per[1:]:  # fp32 accumulation, in microbatch order
+                    g_flat = g_flat + g
+                g_flat = g_flat / m
+                loss = stable_mean0(jnp.stack([l for l, _, _ in per]))
+                metrics = jax.tree.map(
+                    lambda *vs: stable_mean0(jnp.stack(vs)), *[met for _, met, _ in per]
+                )
 
-        new_params, new_opt = apply_prog(params, opt_state, g_flat, step_idx)
+        with jax.profiler.TraceAnnotation("lad.dispatch_apply"):
+            new_params, new_opt = apply_prog(params, opt_state, g_flat, step_idx)
         return new_params, new_opt, loss, metrics
 
     step.self_dispatching = True
@@ -560,19 +570,25 @@ class Trainer:
         history = []
         with self.mesh:
             for i, batch in enumerate(batches):
-                batch = {
-                    k: jax.device_put(
-                        v, NamedSharding(self.mesh, P(self._bsharding.spec[0],
-                                                      *([None] * (v.ndim - 1))))
+                # one ``lad.step`` span per step, carrying its number; the
+                # step's other ``lad.*`` host spans nest inside it
+                with jax.profiler.StepTraceAnnotation("lad.step", step_num=i):
+                    with jax.profiler.TraceAnnotation("lad.place"):
+                        batch = {
+                            k: jax.device_put(
+                                v, NamedSharding(self.mesh, P(self._bsharding.spec[0],
+                                                              *([None] * (v.ndim - 1))))
+                            )
+                            for k, v in batch.items()
+                        }
+                        step_idx = jnp.asarray(i, jnp.int32)
+                    self.params, self.opt_state, loss, metrics = self._jit_step(
+                        self.params, self.opt_state, batch, step_idx
                     )
-                    for k, v in batch.items()
-                }
-                self.params, self.opt_state, loss, metrics = self._jit_step(
-                    self.params, self.opt_state, batch, jnp.asarray(i, jnp.int32)
-                )
-                self.step = i + 1
-                if i % log_every == 0 or i == self.tcfg.steps - 1:
-                    history.append((i, float(loss)))
+                    self.step = i + 1
+                    if i % log_every == 0 or i == self.tcfg.steps - 1:
+                        with jax.profiler.TraceAnnotation("lad.readback"):
+                            history.append((i, float(loss)))
         return history
 
     def save(self, path: str) -> None:
