@@ -122,9 +122,10 @@ class ProtocolConfig:
 
 
 def _encode(cfg: ProtocolConfig, stacked: jax.Array) -> jax.Array:
-    """eq.-(5) per-device combine of the gathered ``(N, d, Q)`` stack (XLA
-    path; kernel backends fuse the gather into ``kernel_ops.gather_combine``
-    and never materialize the stacked gradients)."""
+    """eq.-(5) per-device combine of the assigned ``(N, d, Q)`` rows (XLA
+    path).  On long rows the operand is a stack of selects that XLA fuses
+    into this reduce, so no ``(N, d, Q)`` buffer is written; kernel backends
+    use ``kernel_ops.gather_combine`` instead."""
     del cfg
     return jnp.mean(stacked, axis=1)
 
@@ -151,7 +152,20 @@ def _device_coded_gradients(cfg: ProtocolConfig, key: jax.Array, subset_grads: j
         ta = tm.sample_assignment(key, n, d)
         subsets = ta.subsets
         assign = ta.task_index.astype(jnp.int32)
+    # The encode's form, from static shapes.  Short rows gather.  Long rows
+    # avoid the gather, which XLA:TPU compiles in time that grows with the
+    # row length: d = 1 slices the rows (a permutation XLA folds into the
+    # server's reduce), d > 1 selects them.
     if cfg.backend != "xla":
+        path = "kernel"
+    elif subset_grads.shape[-1] < _LONG_ROW:
+        path = "gather"
+    elif d == 1:
+        path = "slice"
+    else:
+        path = "select"
+    _ENCODE_TRACES[path] += 1  # runs at trace time only
+    if path == "kernel":
         # kernel hot path: assignment gather + eq.-(5) combine fused into one
         # lane-batched launch (under the grid engine's vmap a lane is one
         # scenario; the device axis stays inside the kernel block), so no
@@ -162,28 +176,55 @@ def _device_coded_gradients(cfg: ProtocolConfig, key: jax.Array, subset_grads: j
             subsets,
             assign,
         )
+    if path == "select":
+        return _encode(cfg, _select_rows(subset_grads, subsets)), subsets, assign
     return _encode(cfg, _gather_rows(subset_grads, subsets)), subsets, assign
 
 
-# Row length from which ``_gather_rows`` slices instead of gathering.
+# Row length from which the XLA encode stops gathering rows.
 _LONG_ROW = 1 << 16
+
+# Trace-time count of the encode form each traced program took.
+_ENCODE_TRACES = {"select": 0, "slice": 0, "gather": 0, "kernel": 0}
+
+
+def encode_path_info() -> dict:
+    """{select, slice, gather, kernel}: how many traced programs took each
+    form of the eq.-(5) encode (``_device_coded_gradients``)."""
+    return dict(_ENCODE_TRACES)
 
 
 def _gather_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
     """``x[idx]`` for an ``(N, Q)`` stack and an ``(N, d)`` index table.
 
-    XLA:TPU compiles a gather of whole rows in time that grows with the row
-    length (30 s at ``Q = 2^25``; minutes inside an LM train step), while
-    ``N * d`` unrolled dynamic row slices compile in about a second.  The
-    unrolled form costs compile time in ``N * d`` instead, so short rows
-    (the linear-regression grids: ``Q ~ 100``, ``N * d`` up to thousands)
-    keep the gather.  Both give the same rows bit for bit."""
+    Long rows take ``N * d`` unrolled dynamic row slices: a gather of whole
+    rows compiles in time that grows with the row length on XLA:TPU (30 s at
+    ``Q = 2^25``), the slices in about a second.  Short rows (the
+    linear-regression grids: ``Q ~ 100``, ``N * d`` up to thousands) keep
+    the gather.  Both give the same rows bit for bit."""
     if x.shape[-1] < _LONG_ROW:
         return x[idx]
     return jnp.stack([
         jnp.stack([jax.lax.dynamic_index_in_dim(x, i, keepdims=False) for i in row])
         for row in idx
     ])
+
+
+def _select_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for an ``(N, Q)`` stack and an ``(M, d)`` index table, by
+    selection alone: each output element is one of the ``N`` candidates, so
+    the rows are bit for bit those of the gather (signed zeros and NaN
+    payloads included).  XLA fuses the selects into their consumer, the
+    eq.-(5) reduce, so the ``(M, d, Q)`` result is never written; XLA:TPU
+    copies each candidate row once to a 1-D layout first.  The candidates
+    are taken from an ``(N, 1, Q)`` view, whose rows are contiguous in the
+    layout an all-gathered stack arrives in, so that stack is not relaid
+    out as a whole before the row copies."""
+    n, q = x.shape
+    rows = x.reshape(n, 1, q)
+    shape = idx.shape + (q,)
+    which = jnp.broadcast_to(idx.astype(jnp.int32)[..., None], shape)
+    return jax.lax.select_n(which, *(jnp.broadcast_to(rows[j], shape) for j in range(n)))
 
 
 def _full_server_fn(cfg: ProtocolConfig) -> Callable[[jax.Array], jax.Array]:

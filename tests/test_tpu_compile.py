@@ -112,3 +112,23 @@ def test_pallas_protocol_round_compiles_for_v5e(compressor, one_chip):
     stack = jax.ShapeDtypeStruct((N, 1 << 20), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda k, g: protocol_round(cfg, k, g)).lower(key, stack).compile()
     assert compiled.as_text().count("tpu_custom_call") >= (4 if compressor == "quant" else 3)
+
+
+# Temporary bytes of the XLA round below as the row-slice encode compiled
+# them (commit fd713fd, the same compile on this described v5e): the
+# (N, d, Q) gather and its stacking copies.
+SLICE_ENCODE_TEMP_BYTES = 805_596_672
+
+
+def test_xla_protocol_round_compiles_for_v5e(one_chip):
+    """The engine step's LAD round on the XLA path (select encode, sign-flip,
+    CWTM) on a (4, 2^24) stack: it compiles for the chip and needs no more
+    temporary memory than the row-slice encode did."""
+    cfg = ProtocolConfig(
+        n_devices=4, d=2, method="lad", aggregator="cwtm", trim_frac=0.25,
+        n_byz=1, attack=AttackSpec("sign_flip", n_byz=1),
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    stack = jax.ShapeDtypeStruct((4, 1 << 24), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda k, g: protocol_round(cfg, k, g)).lower(key, stack).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= SLICE_ENCODE_TEMP_BYTES
