@@ -5,15 +5,15 @@
 
 For every seed of ``--seeds``: the program's checked steps (``Trainer`` built
 and driven exactly as a benchmark run does, without the window) against the
-float32 reference.  For every seed of ``--control-seeds``: the control, the
-reference computed with float8 products, against the float32 reference; and
-each planted fault of ``--faults`` (``harness/reference.py``) likewise.  One
-JSON line per reading on stdout, with each leaf's gaps and whether the
-reading passes the cell's limits (``compare.checks`` and ``compare.passed``,
-as a benchmark run applies them), then a summary line: the largest program
-reading and the smallest control and fault readings of each number, and
-which kinds of reading passed.  The benchmark's own runs never run this; it
-needs the cell's chips.
+float32 reference, both of the configuration's ``model_type``.  For every
+seed of ``--control-seeds``: the control, the reference computed with float8
+products, against the float32 reference; and each planted fault of
+``--faults`` (``harness/reference.py``) likewise.  One JSON line per reading
+on stdout, with each leaf's gaps and whether the reading passes the cell's
+limits (``compare.checks`` and ``compare.passed``, as a benchmark run applies
+them), then a summary line: the largest program reading and the smallest
+control and fault readings of each number, and which kinds of reading
+passed.  The benchmark's own runs never run this; it needs the cell's chips.
 """
 import time
 
@@ -56,13 +56,14 @@ def main(argv=None) -> int:
     from harness import compare, device, manifest, reference, traffic
 
     cell = manifest.load_cell(args.workload, args.root)
+    model = manifest.load_model(cell.config["model_type"], args.root)
     cell_lib.enable_cache(args.root)
     if not args.any_device:
         device.require_tpu(jax.devices(), cell.chips)
     rows = []
 
     def emit(kind, seed, prog, ref):
-        values = compare.readings(prog, ref)
+        values = compare.readings(prog, ref, model.reference.APART)
         row = {"kind": kind, "seed": seed, **values,
                "passed": compare.passed(compare.checks(values, cell.limits))}
         rows.append(row)
@@ -75,11 +76,11 @@ def main(argv=None) -> int:
         print(json.dumps(dict(row, leaves=look)), flush=True)
 
     def ref_run(seed, pool, **kw):
-        return reference.run(seed, cell.config, cell.traffic, pool[:cell_lib.CHECKED_STEPS],
-                             cell_lib.CHECKED_STEPS, **kw)
+        return reference.run(model, seed, cell.config, cell.traffic,
+                             pool[:cell_lib.CHECKED_STEPS], cell_lib.CHECKED_STEPS, **kw)
 
     for seed in args.seeds:
-        tr, pool, prog = cell_lib.checked_steps(cell, args.workload, seed, T_START)
+        tr, pool, prog = cell_lib.checked_steps(cell, model, args.workload, seed, T_START)
         del tr
         gc.collect()
         emit("program", seed, prog, ref_run(seed, pool))

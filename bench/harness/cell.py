@@ -1,5 +1,6 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
+The model is the configuration's ``model_type`` (``manifest.load_model``).
 Set-up builds the program's ``Trainer`` once and drives it, through
 ``Trainer.run`` and the same feed as the window, over the first
 ``CHECKED_STEPS`` steps; the first of them compiles (or loads from the
@@ -24,7 +25,7 @@ import time
 
 import jax
 
-from harness import compare, device, flops, manifest, reference, system, trace, traffic
+from harness import compare, device, manifest, reference, stages, system, trace, traffic
 
 CHECKED_STEPS = 3
 PROFILE_OPTIONS = jax.profiler.ProfileOptions()
@@ -76,13 +77,15 @@ def _host_change(p0: dict, p1: dict) -> dict:
     return {k: float(v) for k, v in norms.items()}
 
 
-def checked_steps(cell: manifest.Cell, name: str, seed: int, t_start: float):
-    """Build the program's ``Trainer`` and drive it over the checked steps.
+def checked_steps(cell: manifest.Cell, model: manifest.Model, name: str, seed: int,
+                  t_start: float):
+    """Build the program's ``Trainer`` for ``model`` and drive it over the
+    checked steps.
 
     Returns ``(trainer, pool, readings)``: the same trainer goes on to the
     window; ``readings`` are the program's side of the comparison."""
     config, mix = cell.config, cell.traffic
-    tr = system.make_trainer(name, config, mix, seed)
+    tr = system.make_trainer(model.program.arch_config(name, config), name, config, mix, seed)
     pool = [system.place(tr, b) for b in traffic.batch_pool(seed, config["vocab_size"], mix)]
     p0 = jax.device_get(system.leaves(tr.params))
     _log(t_start, "parameters and batches made")
@@ -115,13 +118,18 @@ def run(name: str, seed: int, seconds: float, traced: bool, *,
         device.require_tpu(devices, cell.chips)
     counter = _CompileCounter()
     config, mix = cell.config, cell.traffic
+    model = manifest.load_model(config["model_type"], root)
     _log(t_start, f"{name}: {devices[0].device_kind} x {len(devices)}")
 
-    tr, pool, prog = checked_steps(cell, name, seed, t_start)
+    tr, pool, prog = checked_steps(cell, model, name, seed, t_start)
     bool(compare.all_finite(tr.params))  # compiled here, read after the window
     # what one step needs: the checked steps read each loss back, so no step
     # is queued behind another (the window's queue grows into whatever is free)
     step_bytes = device.memory_peak_bytes(devices[: cell.chips])
+    # set-up's objects go to the permanent generation, so that a collection
+    # in the window scans what the window allocates, not the whole heap
+    gc.collect()
+    gc.freeze()
     setup_s = time.perf_counter() - t_start
     _log(t_start, "set-up done; window starts")
 
@@ -155,7 +163,8 @@ def run(name: str, seed: int, seconds: float, traced: bool, *,
         events = None
         if traced:
             jax.profiler.stop_trace()
-            events = trace.load(next(pathlib.Path(trace_dir).rglob("*.xplane.pb")))
+            events = stages.load(next(pathlib.Path(trace_dir).rglob("*.xplane.pb")))
+    gc.unfreeze()
     if system.engine_program_cache_info() != programs0 or counter.count != compiles0:
         raise RuntimeError(
             f"a program was compiled inside the window: engine programs "
@@ -172,18 +181,18 @@ def run(name: str, seed: int, seconds: float, traced: bool, *,
     _log(t_start, f"window: {steps} steps in {window_s:.3f} s")
 
     # ------------------------------------------------------------ check
-    ref = reference.run(seed, config, mix, pool, CHECKED_STEPS,
+    ref = reference.run(model, seed, config, mix, pool, CHECKED_STEPS,
                         log=lambda what: _log(t_start, what))
-    checked = compare.checks(compare.readings(prog, ref), cell.limits)
+    checked = compare.checks(compare.readings(prog, ref, model.reference.APART), cell.limits)
     checked["params_nonfinite"] = {"value": 0 if finite else 1, "limit": 0}
     correct = compare.passed(checked)
     _log(t_start, "reference done")
 
     if traced:
-        tr_view = trace.Trace(events)
+        tr_view = stages.StageTrace(events)
         ctx = Context(cell=cell, device_kind=info["kind"], steps=steps, window_s=window_s,
                       tokens_per_s=tokens_per_s, requests=requests,
-                      flops_per_token=flops.train_flops_per_token(config, mix["seq_len"]),
+                      flops_per_token=model.flops.train_flops_per_token(config, mix["seq_len"]),
                       trace=tr_view)
         metrics = {}
         for m in cell.per_layer:

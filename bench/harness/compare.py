@@ -5,13 +5,13 @@ a cell compares those its workload file gives a limit for:
 
 - ``loss_gap``: over the first steps, the largest relative gap of a step's
   loss, |L_prog - L_ref| / |L_ref|;
-- ``grad_norm_gap``: over parameter leaves but the embedding table, the
-  largest gap between the norms of the first aggregated gradient,
-  |n_prog - n_ref| divided by the larger of n_ref and the median leaf's
-  n_ref; ``embed_grad_norm_gap`` the same gap of the embedding table
-  (``APART``), held to a limit of its own: the program sums that leaf's
-  gradient over a subset's tokens in bfloat16, so it reads ten times the
-  others' round-off;
+- ``grad_norm_gap``: over parameter leaves but those the model's
+  reference holds apart (its ``APART``), the largest gap between the norms
+  of the first aggregated gradient, |n_prog - n_ref| divided by the larger
+  of n_ref and the median leaf's n_ref; ``embed_grad_norm_gap`` the same
+  gap, worst leaf, of the leaves held apart, with a limit of its own (for
+  Llama the embedding table: the program sums its gradient over a subset's
+  tokens in bfloat16, so it reads ten times the others' round-off);
 - ``update_norm_gap``: the same of each leaf's change over the steps, worst
   leaf.  Leaves whose first reference gradient is under a thousandth of the
   median leaf's are left out: Adam moves them by round-off alone.
@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 NUMBERS = ("loss_gap", "grad_norm_gap", "embed_grad_norm_gap", "update_norm_gap")
-APART = "embed/table"  # the leaf compared apart from the others
 NEGLIGIBLE_GRAD = 1e-3  # of the median leaf's first gradient norm
 
 
@@ -69,15 +68,16 @@ def moving_leaves(ref: dict) -> list:
     return [k for k, g in ref["first_grad"].items() if g >= NEGLIGIBLE_GRAD * med]
 
 
-def readings(prog: dict, ref: dict) -> dict:
-    """Both arguments as ``reference.run`` returns them."""
+def readings(prog: dict, ref: dict, apart: tuple[str, ...]) -> dict:
+    """``prog`` and ``ref`` as ``reference.run`` returns them; ``apart`` the
+    model's leaves compared apart (its reference's ``APART``)."""
     losses = [_rel(p, r) for p, r in zip(prog["losses"], ref["losses"])]
     grad = leaf_gaps(prog["first_grad"], ref["first_grad"], ref["first_grad"])
     change = leaf_gaps(prog["change"], ref["change"], moving_leaves(ref))
     return {
         "loss_gap": max(losses),
-        "grad_norm_gap": _worst({k: v for k, v in grad.items() if k != APART}),
-        "embed_grad_norm_gap": grad.get(APART, math.inf),
+        "grad_norm_gap": _worst({k: v for k, v in grad.items() if k not in apart}),
+        "embed_grad_norm_gap": _worst({k: v for k, v in grad.items() if k in apart}),
         "update_norm_gap": _worst(change),
     }
 

@@ -6,35 +6,33 @@ steps of training on the batches the benchmark made, and returns what the
 comparison needs: each step's loss, each parameter leaf's first aggregated
 gradient norm and each leaf's change over the steps.
 
-The model is a Llama-architecture decoder with tied embeddings (RMSNorm,
-rotary positions, grouped-query causal attention, SwiGLU), with every
-product in float32 at ``Precision.HIGHEST``.  Stored state keeps the dtypes
-the configuration states: bfloat16 weight matrices and Adam moments, float32
-norm scales.  A step is the LAD round of the paper (Algorithm 1): every
-subset's gradient, the random cyclic assignment of ``d`` subsets to each of
-N workers, the eq.-(5) average, the sign-flip attack of the first ``n_byz``
-workers, and coordinate-wise trimmed mean (or the plain mean of all subset
-gradients for ``protocol: none``); then AdamW with decoupled weight decay
-under a linear-warmup cosine schedule.
-
-The initial weights follow the program's initialiser, which the architecture
-does not fix: truncated normal on [-2, 2] scaled by 1/sqrt(first dim), from
-the key tree ``split(PRNGKey(seed), 5)`` -> per layer ``split(k, 1)[0]`` ->
-``split(., 4)`` (attention, MLP) -> per weight; norm scales are one.
+The model is the configuration's own (``bench/models/<model_type>/
+reference.py``, found by ``manifest.load_model``): its initial weights, its
+leaves' stored dtypes, and its forward pass to a per-token loss plus any
+extra loss term, every product through the function given here.  The rest
+is this module's, the same for every model.  Products are float32 at
+``Precision.HIGHEST``; stored state keeps the dtypes the configuration
+states (the model's leaves; Adam moments in ``momentum_dtype``).  A step is
+the LAD round of the paper (Algorithm 1): every subset's gradient, the
+random cyclic assignment of ``d`` subsets to each of N workers, the eq.-(5)
+average, the sign-flip attack of the first ``n_byz`` workers, and
+coordinate-wise trimmed mean (or the plain mean of all subset gradients for
+``protocol: none``); then AdamW with decoupled weight decay under a
+linear-warmup cosine schedule.
 
 ``mode="fp8"`` is the control: every product takes float8 operands (e4m3
 forward, e5m2 for the cotangents of the backward pass) with one scale per
 tensor.  ``fault`` plants one fault in this step, for the readings that set
-the limits: ``"half_batch"`` averages the loss over the first half of each
-sequence only; ``"no_exchange"`` gives every worker's server only subset 0's
-gradient, as if the gradients were never exchanged; ``"frozen"`` returns
-the parameters and the optimizer's state unchanged.
+the limits: ``"half_batch"`` averages the model's per-token loss over the
+first half of each sequence only; ``"no_exchange"`` gives every worker's
+server only subset 0's gradient, as if the gradients were never exchanged;
+``"frozen"`` returns the parameters and the optimizer's state unchanged.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-import math
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -42,22 +40,11 @@ import jax.numpy as jnp
 from harness.compare import change_norms, leaf_norms
 
 HIGHEST = jax.lax.Precision.HIGHEST
-LAYER_PREFIX = "periods/blk0/"
 
 
 @dataclasses.dataclass(frozen=True)
 class Spec:
-    vocab: int
-    d: int
-    heads: int
-    kv: int
-    hd: int
-    ff: int
-    layers: int
-    norm_eps: float
-    theta: float
-    param_dtype: str
-    norm_dtype: str
+    model: Any  # the model's reference, ``from_config(config)``: frozen, hashable
     n_subsets: int
     rows: int
     seq: int
@@ -80,19 +67,12 @@ class Spec:
     fault: str | None = None
 
 
-def spec_from(config: dict, traffic: dict, mode: str = "f32",
+def spec_from(model, config: dict, traffic: dict, mode: str = "f32",
               fault: str | None = None) -> Spec:
-    if not config["tie_word_embeddings"]:
-        raise ValueError("the reference covers tied embeddings only")
+    """``model`` is the model's part, as ``manifest.load_model`` gives it."""
     train, sched = config["train"], traffic["schedule"]
-    heads = config["num_attention_heads"]
     return Spec(
-        vocab=config["vocab_size"], d=config["hidden_size"], heads=heads,
-        kv=config["num_key_value_heads"],
-        hd=config.get("head_dim", config["hidden_size"] // heads),
-        ff=config["intermediate_size"], layers=config["num_hidden_layers"],
-        norm_eps=float(config["rms_norm_eps"]), theta=float(config["rope_theta"]),
-        param_dtype=train["param_dtype"], norm_dtype=train["norm_dtype"],
+        model=model.reference.from_config(config),
         n_subsets=traffic["n_subsets"], rows=traffic["rows_per_subset"],
         seq=traffic["seq_len"], protocol=traffic["protocol"], d_load=traffic["d"],
         aggregator=traffic["aggregator"], trim_frac=float(traffic["trim_frac"]),
@@ -107,43 +87,11 @@ def spec_from(config: dict, traffic: dict, mode: str = "f32",
 # ----------------------------------------------------------------- weights
 
 
-def _trunc(key, shape, fan_in, dtype):
-    w = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32) * (1.0 / math.sqrt(fan_in))
-    return w.astype(dtype).astype(jnp.float32)
-
-
 @functools.partial(jax.jit, static_argnums=(1,))
-def init_params(seed_key, s: Spec) -> dict:
-    """Leaf name -> float32 array holding the stored (dtype-rounded) value."""
-    dt = jnp.dtype(s.param_dtype)
-    k_emb, k_blocks = jax.random.split(seed_key, 5)[:2]
-
-    def layer(k):
-        k_attn, k_mlp = jax.random.split(jax.random.split(k, 1)[0], 4)[:2]
-        kq, kk, kv, ko = jax.random.split(k_attn, 4)
-        k1, k2, k3 = jax.random.split(k_mlp, 3)
-        return {
-            "mixer/wq": _trunc(kq, (s.d, s.heads, s.hd), s.d, dt),
-            "mixer/wk": _trunc(kk, (s.d, s.kv, s.hd), s.d, dt),
-            "mixer/wv": _trunc(kv, (s.d, s.kv, s.hd), s.d, dt),
-            "mixer/wo": _trunc(ko, (s.heads, s.hd, s.d), s.heads, dt),
-            "mlp/w_gate": _trunc(k1, (s.d, s.ff), s.d, dt),
-            "mlp/w_up": _trunc(k2, (s.d, s.ff), s.d, dt),
-            "mlp/w_down": _trunc(k3, (s.ff, s.d), s.ff, dt),
-        }
-
-    stacked = jax.vmap(layer)(jax.random.split(k_blocks, s.layers))
-    params = {LAYER_PREFIX + k: v for k, v in stacked.items()}
-    ones = jnp.ones((s.layers, s.d), jnp.float32)
-    params[LAYER_PREFIX + "ln1"] = ones
-    params[LAYER_PREFIX + "ln2"] = ones
-    params["embed/table"] = _trunc(k_emb, (s.vocab, s.d), s.vocab, dt)
-    params["ln_f"] = jnp.ones((s.d,), jnp.float32)
-    return params
-
-
-def leaf_dtype(s: Spec, name: str):
-    return jnp.dtype(s.norm_dtype if name.endswith(("ln1", "ln2", "ln_f")) else s.param_dtype)
+def init_params(seed_key, model) -> dict:
+    """The model's initial leaves, in one call: leaf name -> float32 array
+    holding the stored (dtype-rounded) value."""
+    return model.init_params(seed_key)
 
 
 # ----------------------------------------------------------------- products
@@ -178,55 +126,16 @@ def _fp8_bwd(eq, res, ct):
 _fp8_einsum.defvjp(_fp8_fwd, _fp8_bwd)
 
 
-# ----------------------------------------------------------------- model
-
-
-def _rmsnorm(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, theta):
-    """Rotary positions, rotate-half form: x (B, S, heads, hd)."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None, None] * freqs
-    x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * jnp.cos(ang) - x2 * jnp.sin(ang),
-                            x2 * jnp.cos(ang) + x1 * jnp.sin(ang)], axis=-1)
-
-
-def _layer(s: Spec, mm, x, w):
-    b, n = x.shape[:2]
-    h = _rmsnorm(x, w["ln1"], s.norm_eps)
-    q = _rope(mm("bsd,dhk->bshk", h, w["mixer/wq"]), s.theta)
-    k = _rope(mm("bsd,dhk->bshk", h, w["mixer/wk"]), s.theta)
-    v = mm("bsd,dhk->bshk", h, w["mixer/wv"])
-    q = q.reshape(b, n, s.kv, s.heads // s.kv, s.hd)  # query head j reads kv head j // g
-    logits = mm("bqhgd,bkhd->bhgqk", q, k) * (1.0 / math.sqrt(s.hd))
-    causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
-    probs = jax.nn.softmax(jnp.where(causal, logits, -jnp.inf), axis=-1)
-    o = mm("bhgqk,bkhd->bqhgd", probs, v).reshape(b, n, s.heads, s.hd)
-    x = x + mm("bshk,hkd->bsd", o, w["mixer/wo"])
-    h = _rmsnorm(x, w["ln2"], s.norm_eps)
-    act = jax.nn.silu(mm("bsd,df->bsf", h, w["mlp/w_gate"])) * mm("bsd,df->bsf", h, w["mlp/w_up"])
-    return x + mm("bsf,fd->bsd", act, w["mlp/w_down"])
+# ----------------------------------------------------------------- loss
 
 
 def loss_fn(params, tokens, labels, s: Spec):
-    """Mean next-token cross entropy of one subset's rows."""
+    """Mean per-token loss of one subset's rows, plus the model's extra term."""
     mm = _fp8_einsum if s.mode == "fp8" else _einsum
-    layers = {k[len(LAYER_PREFIX):]: v for k, v in params.items() if k.startswith(LAYER_PREFIX)}
-    table = params["embed/table"]
-    x = table[tokens]
-    body = jax.checkpoint(lambda x, w: (_layer(s, mm, x, w), None))
-    x, _ = jax.lax.scan(body, x, layers)
-    x = _rmsnorm(x, params["ln_f"], s.norm_eps)
-    logits = mm("bsd,vd->bsv", x, table)
-    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-        logits, labels[..., None], axis=-1)[..., 0]
+    nll, extra = s.model.forward(params, tokens, labels, mm)
     if s.fault == "half_batch":
         nll = nll[:, : s.seq // 2]
-    return jnp.mean(nll)
+    return jnp.mean(nll) + extra
 
 
 # ----------------------------------------------------------------- protocol
@@ -291,18 +200,19 @@ def leaf_step(p, m, v, stack, key, step, s: Spec, dtype):
     return p.astype(dtype).astype(jnp.float32), m32.astype(md), v32.astype(md), g
 
 
-def run(seed: int, config: dict, traffic: dict, batches: list[dict], steps: int,
+def run(model, seed: int, config: dict, traffic: dict, batches: list[dict], steps: int,
         mode: str = "f32", fault: str | None = None, log=None) -> dict:
-    """The reference's readings over the first ``steps`` steps of training.
+    """The reference's readings over the first ``steps`` steps of training
+    of ``model`` (``manifest.load_model``).
 
     Returns ``{"losses": [...], "first_grad": {leaf: norm}, "change":
     {leaf: norm}}`` with Python floats.  ``log(text)``, if given, hears when
     each step is done."""
-    s = spec_from(config, traffic, mode, fault)
+    s = spec_from(model, config, traffic, mode, fault)
     base = jax.random.PRNGKey(seed)
-    params = init_params(base, s)
+    params = init_params(base, s.model)
     # a copy in the stored dtype (exact): the step donates ``params``
-    p0 = {k: jnp.array(x, dtype=leaf_dtype(s, k), copy=True) for k, x in params.items()}
+    p0 = {k: jnp.array(x, dtype=s.model.leaf_dtype(k), copy=True) for k, x in params.items()}
     md = jnp.dtype(s.momentum_dtype)
     m = {k: jnp.zeros(x.shape, md) for k, x in p0.items()}
     v = {k: jnp.zeros(x.shape, md) for k, x in p0.items()}
@@ -315,7 +225,7 @@ def run(seed: int, config: dict, traffic: dict, batches: list[dict], steps: int,
         for k in sorted(grads):
             params[k], m[k], v[k], agg[k] = leaf_step(
                 params[k], m[k], v[k], grads.pop(k), round_key, jnp.int32(i), s,
-                leaf_dtype(s, k))
+                s.model.leaf_dtype(k))
         if i == 0:
             first = leaf_norms(agg)
         del agg

@@ -250,7 +250,10 @@ def cut(events: list[Event], steps: int) -> list[Event]:
 
 
 class StageTrace(trace.Trace):
-    """A ``trace.Trace`` whose op events carry their scope."""
+    """A ``trace.Trace`` whose op events carry their scope, and whose idle
+    gaps are named by the ``lad.*`` span the host was in."""
+
+    gap_labels = GAP_LABELS
 
     def __init__(self, events: list[Event]):
         super().__init__(events)
@@ -310,21 +313,6 @@ class StageTrace(trace.Trace):
         return sum(t - s for s, t in trace.merge(
             (max(e.start, self.start), min(e.end, self.end)) for e in self.spans
             if e.name == name and e.end > self.start and e.start < self.end))
-
-    def idle_gaps(self, device: str, n: int = 10) -> list[list]:
-        """``trace.Trace.idle_gaps`` with the ``lad.*`` spans among the names:
-        each gap is named by the program's step part the host was in."""
-        busy = trace.merge((s, t) for _, s, t in self._of(device, "op"))
-        edges = [self.start] + [x for iv in busy for x in iv] + [self.end]
-        gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-                if edges[i + 1] > edges[i]]
-        named = []
-        for s, t in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
-            mid = 0.5 * (s + t)
-            around = [e for e in self.spans if e.start <= mid <= e.end and e.name in GAP_LABELS]
-            inner = min(around, key=lambda e: e.end - e.start, default=None)
-            named.append([GAP_LABELS[inner.name] if inner else "outside", t - s])
-        return named
 
 
 def _inside(pieces, spans):

@@ -1,8 +1,9 @@
 """The system under test, as the benchmark drives it.
 
-This is the only module of the benchmark that imports the program
-(``src/repro``): it turns a configuration file and a traffic file into the
-program's own ``ArchConfig`` and ``TrainConfig``, builds ``Trainer`` with the
+With each model's ``bench/models/<model_type>/program.py`` (which maps a
+configuration file to the program's ``ArchConfig``) this is the only part of
+the benchmark that imports the program (``src/repro``): it turns a traffic
+file into the program's ``TrainConfig``, builds ``Trainer`` with the
 protocol-engine step, and reads the state the comparison needs.
 """
 from __future__ import annotations
@@ -10,24 +11,9 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding
 
-from repro.configs.base import ArchConfig, BlockSpec, TrainConfig
+from repro.configs.base import ArchConfig, TrainConfig
 from repro.launch.mesh import make_host_mesh
 from repro.launch.train import Trainer, batch_pspec, engine_program_cache_info  # noqa: F401
-
-
-def arch_config(name: str, config: dict) -> ArchConfig:
-    heads = config["num_attention_heads"]
-    return ArchConfig(
-        name=name, family="dense", source=config["source"]["url"],
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=heads, n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        head_dim=config.get("head_dim", config["hidden_size"] // heads),
-        period=(BlockSpec(),), rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=config["tie_word_embeddings"],
-        param_dtype=config["train"]["param_dtype"],
-    )
 
 
 def train_config(name: str, config: dict, traffic: dict, seed: int) -> TrainConfig:
@@ -48,8 +34,10 @@ def train_config(name: str, config: dict, traffic: dict, seed: int) -> TrainConf
     )
 
 
-def make_trainer(name: str, config: dict, traffic: dict, seed: int) -> Trainer:
-    return Trainer(cfg=arch_config(name, config),
+def make_trainer(arch: ArchConfig, name: str, config: dict, traffic: dict,
+                 seed: int) -> Trainer:
+    """``arch`` from the model's ``program.arch_config(name, config)``."""
+    return Trainer(cfg=arch,
                    tcfg=train_config(name, config, traffic, seed),
                    mesh=make_host_mesh(1, 1))
 

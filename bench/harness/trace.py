@@ -92,6 +92,8 @@ def _clip(events, lo, hi):
 class Trace:
     """The events of one traced window."""
 
+    gap_labels = GAP_LABELS  # the host spans that name an idle gap
+
     def __init__(self, events: list[Event]):
         windows = [e for e in events if e.where == "host" and e.name == WINDOW_SPAN]
         if len(windows) != 1:
@@ -144,7 +146,8 @@ class Trace:
 
     def idle_gaps(self, device: str, n: int = 10) -> list[list]:
         """The ``n`` longest gaps between operations on ``device`` inside the
-        window, each named by the innermost host span around its midpoint."""
+        window, each named by the innermost host span around its midpoint
+        that ``gap_labels`` names."""
         busy = merge((s, t) for _, s, t in self._of(device, "op"))
         edges = [self.start] + [x for iv in busy for x in iv] + [self.end]
         gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -152,7 +155,8 @@ class Trace:
         named = []
         for s, t in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
             mid = 0.5 * (s + t)
-            around = [e for e in self.spans if e.start <= mid <= e.end and e.name in GAP_LABELS]
+            around = [e for e in self.spans
+                      if e.start <= mid <= e.end and e.name in self.gap_labels]
             inner = min(around, key=lambda e: e.end - e.start, default=None)
-            named.append([GAP_LABELS[inner.name] if inner else "outside", t - s])
+            named.append([self.gap_labels[inner.name] if inner else "outside", t - s])
         return named

@@ -1,6 +1,7 @@
 """Model FLOP/s utilization of the whole step: training tokens per second
-of one honest copy times the model FLOPs per token (``harness/flops.py``),
-over the chips' bf16 peak (``harness/device.py``)."""
+of one honest copy times the model FLOPs per token (the configuration's
+``bench/models/<model_type>/flops.py``, as ``ctx.flops_per_token``), over
+the chips' bf16 peak (``harness/device.py``)."""
 
 from harness.device import peaks
 

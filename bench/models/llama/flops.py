@@ -1,4 +1,5 @@
-"""Model FLOPs of one training token, counted from a configuration file.
+"""Model FLOPs of one training token of a Llama decoder, counted from a
+configuration file.
 
 Only the work one honest copy of the model needs: forward plus backward
 (3x forward) of every matrix product, with the tied embedding counted once

@@ -4,16 +4,17 @@
         [--out DIR] [--fixture-steps K] [--xplane]
 
 Runs the cell as ``bench/run.py --trace 1`` does (``harness/cell.py``, set-up,
-window, check and readers unchanged) and prints its result line.  The same
-``.xplane.pb`` is read a second time by ``harness/stages.py``, and one more
-JSON line follows: per step and averaged over the chips, each stage's device
-self time, the op time under no stage inside the round program, the host time
-in each ``lad.*`` span, the traced rate, the first chip's longest idle gaps
-named by ``lad.*`` span, and on more than one chip whether any op runs
-inside the gradient stack's all-gather.  With ``--out`` the window's events
-(with scopes) are written there, those of its first ``K`` steps too with
-``--fixture-steps`` (a test fixture), and the raw profile with ``--xplane``.  It needs the
-cell's chips; the benchmark's own runs never run it.
+window, check and readers unchanged) and prints its result line.  The events
+that run read from its ``.xplane.pb`` (``harness/stages.py``) are kept, and
+one more JSON line follows: per step and averaged over the chips, each stage's
+device self time, the op time under no stage inside the round program, the
+host time in each ``lad.*`` span, the traced rate, the first chip's longest
+idle gaps named by ``lad.*`` span, and on more than one chip whether any op
+runs inside the gradient stack's all-gather.  With ``--out`` the window's
+events (with scopes) are written there, those of its first ``K`` steps too
+with ``--fixture-steps`` (a test fixture), and the raw profile with
+``--xplane``.  It needs the cell's chips; the benchmark's own runs never run
+it.
 """
 import time
 
@@ -71,19 +72,19 @@ def summary(t, steps: int, tokens_per_step: int) -> dict:
 def run(workload: str, seed: int, seconds: float, *, xplane_to=None, **kw):
     """``cell.run`` of a traced window, and its profile as ``stages.load``
     reads it: ``(result, events)``.  ``kw`` goes to ``cell.run``."""
-    from harness import cell, trace
+    from harness import cell
 
     kept = {}
 
-    def load_both(path):  # read the profile before cell.run deletes it
+    def keep(path):  # read the profile before cell.run deletes it
         if xplane_to is not None:
             shutil.copy(path, xplane_to)
         kept["events"] = stages.load(path)
-        return trace.load(path)
+        return kept["events"]
 
-    # cell.run sees the trace module with this one function replaced
-    with mock.patch.object(cell, "trace", types.SimpleNamespace(
-            **{**vars(trace), "load": load_both})):
+    # cell.run sees the stages module with this one function replaced
+    with mock.patch.object(cell, "stages", types.SimpleNamespace(
+            **{**vars(stages), "load": keep})):
         result = cell.run(workload, seed, seconds, True, **kw)
     return result, kept["events"]
 

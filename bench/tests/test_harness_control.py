@@ -43,13 +43,16 @@ def _run_tiny(root, monkeypatch):
 def test_control_fails_the_limits(tmp_path, seed):
     root = tiny.make_root(tmp_path)
     cell = manifest.load_cell("tiny.lad", root)
+    model = manifest.load_model(cell.config["model_type"], root)
     pool = traffic.batch_pool(seed, cell.config["vocab_size"], cell.traffic)
     steps = cell_lib.CHECKED_STEPS
 
     def ref(mode):
-        return reference.run(seed, cell.config, cell.traffic, pool[:steps], steps, mode=mode)
+        return reference.run(model, seed, cell.config, cell.traffic, pool[:steps], steps,
+                             mode=mode)
 
-    checked = compare.checks(compare.readings(ref("fp8"), ref("f32")), cell.limits)
+    checked = compare.checks(compare.readings(ref("fp8"), ref("f32"), model.reference.APART),
+                             cell.limits)
     assert not compare.passed(checked), checked
 
 

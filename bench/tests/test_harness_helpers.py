@@ -9,9 +9,10 @@ import sys
 import jax.numpy as jnp
 import pytest
 
-from harness import compare, device, flops, manifest, traffic
+from harness import compare, device, manifest, traffic
 
 ROOT = manifest.ROOT
+LLAMA = manifest.load_model("llama")
 
 
 def _config(name="smollm360m-l20"):
@@ -20,6 +21,7 @@ def _config(name="smollm360m-l20"):
 
 def test_flops_match_hand_count():
     cfg = _config()
+    flops = manifest.load_model(cfg["model_type"]).flops
     # per layer: wq, wo 960x960 each; wk, wv 960x320 each; SwiGLU 3 x 960x2560
     per_layer = 2 * 960 * 960 + 2 * 960 * 320 + 3 * 960 * 2560
     assert per_layer == 9_830_400
@@ -74,12 +76,12 @@ def test_batch_pool_is_fixed_by_the_seed():
 
 
 def test_readings_take_the_worst_leaf_against_the_median():
-    t = compare.APART
+    (t,) = LLAMA.reference.APART
     ref = {"losses": [10.0, 9.0], "first_grad": {"a": 1.0, "b": 2.0, "c": 1e-6, t: 3.0},
            "change": {"a": 0.5, "b": 0.5, "c": 0.0, t: 0.5}}
     prog = {"losses": [10.1, 9.0], "first_grad": {"a": 1.1, "b": 2.0, "c": 0.1, t: 3.9},
             "change": {"a": 0.5, "b": 0.4, "c": 0.3, t: 0.5}}
-    got = compare.readings(prog, ref)
+    got = compare.readings(prog, ref, LLAMA.reference.APART)
     assert got["loss_gap"] == pytest.approx(0.01)
     # leaves a and c: 0.1 / median(1, 2, 1e-6, 3) = 0.1 / 1.5; the embedding
     # table, 0.9 / 3, is compared apart
@@ -101,5 +103,5 @@ def test_readings_take_the_worst_leaf_against_the_median():
 def test_readings_fail_on_nan_and_on_missing_leaves():
     ref = {"losses": [1.0], "first_grad": {"a": 1.0}, "change": {"a": 1.0}}
     got = compare.readings({"losses": [float("nan")], "first_grad": {"b": 1.0},
-                            "change": {"a": 1.0}}, ref)
+                            "change": {"a": 1.0}}, ref, LLAMA.reference.APART)
     assert got["loss_gap"] == float("inf") and got["grad_norm_gap"] == float("inf")

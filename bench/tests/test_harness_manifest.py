@@ -1,9 +1,7 @@
 """CPU tests of BENCHMARK.json and the files it names: every cell, config,
 traffic mix and metric resolves by name, the names keep to the contract's
 characters, and an addition needs new files only."""
-import hashlib
 import json
-import pathlib
 
 import pytest
 
@@ -100,16 +98,11 @@ def test_moves_is_reported_wherever_the_metric_is():
             assert manifest.reports(e2e[m["moves"]], cell)
 
 
-def _digest(root: pathlib.Path) -> dict:
-    return {str(p.relative_to(root)): hashlib.sha256(p.read_bytes()).hexdigest()
-            for p in sorted(root.rglob("*")) if p.is_file() and ".cache" not in p.parts}
-
-
 def test_an_addition_runs_without_editing_an_existing_file(tmp_path, monkeypatch):
     """A new configuration, traffic mix, cell and metric, added to a copy of
     the benchmark as new files and new entries, resolve and run."""
     root = tiny.make_root(tmp_path)
-    before = _digest(root / "bench")
+    before = tiny.digest(root / "bench")
     # the additions: four new files, and new entries in BENCHMARK.json
     (root / "bench" / "configs" / "tiny2.json").write_text(
         (root / "bench" / "configs" / "tiny.json").read_text())
@@ -127,7 +120,7 @@ def test_an_addition_runs_without_editing_an_existing_file(tmp_path, monkeypatch
                               "source": "host_clock", "layer": "training loop", "moves": "tokens_per_s",
                               "workloads": ["tiny2.plain"]})
     (root / "BENCHMARK.json").write_text(json.dumps(data))
-    after = _digest(root / "bench")
+    after = tiny.digest(root / "bench")
     assert all(after[k] == v for k, v in before.items())  # nothing existing was edited
 
     monkeypatch.setitem(device.PEAKS, "cpu", device.PEAKS["TPU v5 lite"])

@@ -3,6 +3,7 @@ readers, with configuration and traffic files shrunk so a run takes seconds.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import pathlib
 import shutil
@@ -10,6 +11,7 @@ import shutil
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
 CONFIG = {
+    "model_type": "llama",
     "hidden_size": 64,
     "intermediate_size": 128,
     "num_attention_heads": 4,
@@ -39,6 +41,13 @@ LIMITS = {"loss_gap": 4e-4, "grad_norm_gap": 5.5e-3, "embed_grad_norm_gap": 4.5e
           "update_norm_gap": 1.4e-3}
 
 
+def digest(root: pathlib.Path) -> dict:
+    """File -> sha256 of everything under ``root`` but compile caches."""
+    return {str(f.relative_to(root)): hashlib.sha256(f.read_bytes()).hexdigest()
+            for f in sorted(root.rglob("*"))
+            if f.is_file() and ".cache" not in f.parts and "__pycache__" not in f.parts}
+
+
 def make_root(tmp: pathlib.Path, *, chips: int = 1, shard: str = "none",
               traffic: dict | None = None, limits: dict | None = None) -> pathlib.Path:
     """A checkout-shaped directory holding one cell ``tiny.lad``."""
@@ -46,7 +55,9 @@ def make_root(tmp: pathlib.Path, *, chips: int = 1, shard: str = "none",
     bench = root / "bench"
     for sub in ("configs", "traffic", "workloads"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(BENCH / "metrics", bench / "metrics", dirs_exist_ok=True)
+    for sub in ("metrics", "models"):
+        shutil.copytree(BENCH / sub, bench / sub, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     real = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     config = dict(CONFIG, train=json.loads(
         (BENCH / "configs" / "smollm360m-l20.json").read_text())["train"],
@@ -65,3 +76,81 @@ def make_root(tmp: pathlib.Path, *, chips: int = 1, shard: str = "none",
     }
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     return root
+
+
+# A second architecture, as a later change would add one: a Llama with an
+# untied output head (``lm_head``), in files of its own under
+# ``bench/models/tiny_untied/`` that build on Llama's.
+UNTIED_FILES = {
+    "program.py": '''"""A Llama with an untied output head: the program's dense family."""
+import dataclasses
+import pathlib
+
+from harness import manifest
+
+LLAMA = manifest.load_model("llama", pathlib.Path(__file__).resolve().parents[3])
+
+
+def arch_config(name, config):
+    return dataclasses.replace(LLAMA.program.arch_config(name, config), tie_embeddings=False)
+''',
+    "reference.py": '''"""A Llama with an untied output head ``lm_head`` (vocab, d), initialised
+from the program's head key, ``split(PRNGKey(seed), 5)[2]``."""
+import dataclasses
+import pathlib
+
+import jax
+import jax.numpy as jnp
+
+from harness import manifest
+
+llama = manifest.load_model("llama", pathlib.Path(__file__).resolve().parents[3]).reference
+APART = llama.APART
+
+
+@dataclasses.dataclass(frozen=True)
+class Untied(llama.Llama):
+    def init_params(self, seed_key):
+        params = super().init_params(seed_key)
+        k_head = jax.random.split(seed_key, 5)[2]
+        params["lm_head"] = llama.trunc(k_head, (self.vocab, self.d), self.vocab,
+                                        jnp.dtype(self.param_dtype))
+        return params
+
+    def forward(self, params, tokens, labels, mm):
+        logits = mm("bsd,vd->bsv", self.hidden(params, tokens, mm), params["lm_head"])
+        return llama.token_nll(logits, labels), 0.0
+
+
+def from_config(config):
+    return Untied(**llama.sizes(config))
+''',
+    "flops.py": '''"""As Llama's: the untied head is one matrix product, as the tied one."""
+import pathlib
+
+from harness import manifest
+
+train_flops_per_token = manifest.load_model(
+    "llama", pathlib.Path(__file__).resolve().parents[3]).flops.train_flops_per_token
+''',
+}
+
+
+def add_untied_model(root: pathlib.Path) -> str:
+    """Add the untied model and a cell of it to ``make_root``'s ``root`` by
+    new files and new entries only; returns the cell's name."""
+    bench = root / "bench"
+    (bench / "models" / "tiny_untied").mkdir()
+    for name, text in UNTIED_FILES.items():
+        (bench / "models" / "tiny_untied" / name).write_text(text)
+    config = json.loads((bench / "configs" / "tiny.json").read_text())
+    config.update(model_type="tiny_untied", tie_word_embeddings=False)
+    (bench / "configs" / "tiny_untied.json").write_text(json.dumps(config))
+    (bench / "workloads" / "tiny_untied.lad.json").write_text(json.dumps({"limits": LIMITS}))
+    data = json.loads((root / "BENCHMARK.json").read_text())
+    data["configs"].append(dict(data["configs"][0], name="tiny_untied",
+                                file="bench/configs/tiny_untied.json"))
+    data["workloads"].append(dict(data["workloads"][0], name="tiny_untied.lad",
+                                  config="tiny_untied"))
+    (root / "BENCHMARK.json").write_text(json.dumps(data))
+    return "tiny_untied.lad"
