@@ -25,6 +25,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.numerics import sort_rows
+
 Aggregator = Callable[[jax.Array], jax.Array]
 
 __all__ = [
@@ -52,16 +54,30 @@ def coordinate_median(msgs: jax.Array) -> jax.Array:
     return jnp.median(msgs, axis=0)
 
 
+# CWTM orders at most this many message rows with ``sort_rows``' compare-
+# exchange network and more with ``jnp.sort``.  The network costs N(N-1)/2
+# exchanges; the sort writes a sorted copy of the stack and an index array.
+# On one v5e chip, an (N, 2^26) fp32 stack at f = N/4 (scripts/cwtm_cut.py,
+# recorded in PERF.md): network / sort 6.85 / 17.52 ms at N = 4,
+# 23.80 / 28.11 at N = 8, 82.94 / 71.48 at N = 16.
+CWTM_NETWORK_MAX_N = 8
+
+
 def cwtm(msgs: jax.Array, trim_frac: float = 0.1) -> jax.Array:
     """Coordinate-wise trimmed mean: drop the ``f`` largest and smallest
     values per coordinate, average the rest.  ``f = floor(trim_frac * N)``.
+
+    The order is ``jnp.sort``'s (stable, NaN last), so a NaN or inf row is
+    trimmed like any other extreme; the kept rows are the sort's to the bit.
     """
     n = msgs.shape[0]
     f = int(trim_frac * n)
     if 2 * f >= n:
         raise ValueError(f"trim_frac={trim_frac} removes all {n} messages")
-    srt = jnp.sort(msgs, axis=0)
-    kept = srt[f : n - f] if f > 0 else srt
+    if n <= CWTM_NETWORK_MAX_N:
+        kept = jnp.stack(sort_rows([msgs[i] for i in range(n)])[f : n - f])
+    else:
+        kept = jnp.sort(msgs, axis=0)[f : n - f]
     return jnp.mean(kept, axis=0)
 
 

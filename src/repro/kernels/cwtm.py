@@ -7,9 +7,10 @@ instead of materializing the ``(N, Q)`` sorted intermediate in HBM (3x HBM
 traffic for a jnp.sort-based implementation: read + sorted write + read).
 
 The per-coordinate sort over the tiny static ``N`` axis (16/32 devices) is an
-odd-even transposition network: ``N`` compare-exchange passes on vectors of
-width ``q_block`` — each pass is a vectorized min/max on the VPU, no data-
-dependent control flow.
+odd-even transposition network (``numerics.sort_rows``, shared with the XLA
+server ``aggregators.cwtm``): ``N`` compare-exchange passes on vectors of
+width ``q_block`` — each exchange a vectorized compare and select on the VPU,
+no data-dependent control flow, NaN ordered last as ``jnp.sort`` does.
 
 Tiling: the canonical entry point is **lane-batched** — ``(L, N, Q)`` stacks
 of independent scenario lanes over a 2-D ``(lane, q_tile)`` grid, each program
@@ -28,27 +29,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.tiling import row_tiles
-from repro.numerics import tree_sum_rows
-
-
-def _sort_rows(rows: list) -> list:
-    """Odd-even transposition sort of a list of equal-shape rows: ``n``
-    passes of compare-exchanges between neighbouring rows (static,
-    branch-free; each exchange is a vectorized min/max on the VPU)."""
-    rows = list(rows)
-    n = len(rows)
-    for phase in range(n):
-        for i in range(phase % 2, n - 1, 2):
-            a, b = rows[i], rows[i + 1]
-            rows[i], rows[i + 1] = jnp.minimum(a, b), jnp.maximum(a, b)
-    return rows
+from repro.numerics import sort_rows, tree_sum_rows
 
 
 def _cwtm_kernel(msgs_ref, out_ref, *, trim: int):
     n = msgs_ref.shape[1]
     # this lane's (N, q_block) tile as N static single-row (1, q_block) loads
     rows = [msgs_ref[0, r : r + 1, :].astype(jnp.float32) for r in range(n)]
-    kept = _sort_rows(rows)[trim : n - trim]
+    kept = sort_rows(rows)[trim : n - trim]
     # fixed-tree mean, not jnp.mean: a reduce op may accumulate in a
     # different order per program shape, breaking the engine's cross-mode
     # bitwise guarantee (see repro/numerics.py)

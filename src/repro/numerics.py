@@ -40,7 +40,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["tree_sum", "tree_sum_rows", "stable_norm", "stable_mean0", "stable_masked_mean0"]
+__all__ = [
+    "tree_sum", "tree_sum_rows", "sort_rows", "stable_norm", "stable_mean0",
+    "stable_masked_mean0",
+]
 
 
 def _pad_pow2(v: jax.Array, axis: int) -> jax.Array:
@@ -82,6 +85,27 @@ def tree_sum_rows(rows) -> jax.Array:
         h = len(rows) // 2
         rows = [rows[i] + rows[i + h] for i in range(h)]
     return rows[0]
+
+
+def sort_rows(rows) -> list:
+    """Sort a list of equal-shape arrays elementwise, in ``jnp.sort``'s order:
+    stable, ``-0 == +0``, NaN last.
+
+    An odd-even transposition network: ``n`` passes of compare-exchanges
+    between neighbouring rows, static and branch-free.  An exchange swaps only
+    where ``b < a`` or ``a`` is NaN and ``b`` is not, so equal values keep
+    their order and a NaN stays in its own slot (``minimum``/``maximum``
+    would spread it to both).  NaN is found by comparison alone (``b == b``
+    is false only for NaN), so the same network lowers in a Pallas kernel.
+    """
+    rows = list(rows)
+    n = len(rows)
+    for phase in range(n):
+        for i in range(phase % 2, n - 1, 2):
+            a, b = rows[i], rows[i + 1]
+            swap = (b == b) & ~(a <= b)  # b < a, or a is NaN and b is not
+            rows[i], rows[i + 1] = jnp.where(swap, b, a), jnp.where(swap, a, b)
+    return rows
 
 
 def stable_norm(v: jax.Array) -> jax.Array:

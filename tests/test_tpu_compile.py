@@ -10,6 +10,8 @@ inside a module fixture (never at import), which skips when the TPU compiler
 cannot be loaded here; the persistent compilation cache is off around the
 compiles, since a compile for a described chip cannot be read back.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -132,3 +134,34 @@ def test_xla_protocol_round_compiles_for_v5e(one_chip):
     stack = jax.ShapeDtypeStruct((4, 1 << 24), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda k, g: protocol_round(cfg, k, g)).lower(key, stack).compile()
     assert compiled.memory_analysis().temp_size_in_bytes <= SLICE_ENCODE_TEMP_BYTES
+
+
+# Temporary bytes of the same round with CWTM as a stable sort of the stack
+# (commit 15d830a, the same compile on this described v5e): the sorted copy,
+# its s32 index iota and the sorted indices.
+SORT_CWTM_TEMP_BYTES = 537_128_960
+
+
+def test_xla_round_cwtm_is_one_fusion_for_v5e(one_chip):
+    """The same round's server orders the four rows with the compare-exchange
+    network: no sort and no index iota over the stack, one fusion for the
+    whole of ``lad.aggregate``, and less temporary memory than the sort."""
+    q = 1 << 24
+    cfg = ProtocolConfig(
+        n_devices=4, d=2, method="lad", aggregator="cwtm", trim_frac=0.25,
+        n_byz=1, attack=AttackSpec("sign_flip", n_byz=1),
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    stack = jax.ShapeDtypeStruct((4, q), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda k, g: protocol_round(cfg, k, g)).lower(key, stack).compile()
+    hlo = compiled.as_text()
+    large = [line for line in hlo.splitlines() if f",{q}]" in line]
+    assert not [line for line in large if re.search(r"\b(sort|iota)\(", line)]
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    aggregate = [
+        line for line in entry.splitlines()
+        if "lad.aggregate" in line and f",{q}]" in line and " bitcast(" not in line
+    ]
+    assert len(aggregate) == 1 and " fusion(" in aggregate[0], aggregate
+    assert compiled.memory_analysis().temp_size_in_bytes < SORT_CWTM_TEMP_BYTES
