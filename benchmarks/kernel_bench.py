@@ -1,5 +1,6 @@
 """Microbenchmarks of the protocol hot-spots (CPU timings: relative only;
-the TPU picture comes from the dry-run roofline, not from these timings).
+the TPU picture comes from the chip benchmark under ``bench/``, not from
+these timings).
 
 Besides the single-call rows, ``lane_batched_bench`` times every Pallas
 kernel in its lane-batched form (ONE 2-D ``(lane, q_tile)`` grid launch over

@@ -524,7 +524,7 @@ def zoo_serve(
 
     For every zoo family (``scenarios.ZOO_FAMILIES``) three engine-path
     trainers run on identical heterogeneous-LM data through
-    ``build_engine_step``:
+    ``build_train_step``:
 
       * **clean**      — ``protocol="none"`` (honest mean, no attack);
       * **robust**     — ``protocol="lad"`` (d=2 cyclic code + CWTM) under a

@@ -17,8 +17,8 @@ Phases (each raises on failure, so the script exits non-zero):
    ``LM_LAYERS`` whole layers to fit one 16 GiB chip), LAD d=2 + CWTM
    under sign-flip through ``launch.train.Trainer``'s protocol-engine step,
    8 adamw steps on one fixed seeded batch at seq 512.
-5. (``--four-chips``) the two sharded train paths against their unsharded
-   or all-gather counterparts on the same inputs.
+5. (``--four-chips``) the engine train step with ``shard="shard_map"`` over
+   the four chips against ``shard="none"`` on the same inputs.
 
 Other lines are information; the last line of stdout is one JSON object,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -190,8 +190,7 @@ def _lm_batch(cfg, n_subsets: int) -> dict:
 
 def phase_lm_train() -> None:
     cfg = _lm_cfg(LM_LAYERS)
-    tr = Trainer(cfg=cfg, tcfg=_lm_tcfg(cfg, protocol_impl="engine"),
-                 mesh=make_host_mesh(1, 1))
+    tr = Trainer(cfg=cfg, tcfg=_lm_tcfg(cfg), mesh=make_host_mesh(1, 1))
     n_params = sum(x.size for x in jax.tree.leaves(tr.params))
     batch = _lm_batch(cfg, LM_SUBSETS)
     stamps = []
@@ -241,22 +240,13 @@ def phase_four_chips() -> None:
         return _lm_tcfg(cfg, optimizer="sgd", steps=FOUR_CHIP_STEPS, **kw)
 
     mesh = make_host_mesh(1, 1)
-    runs = {s: _params_after(cfg, tcfg(protocol_impl="engine", shard=s), mesh, batch)
+    runs = {s: _params_after(cfg, tcfg(shard=s), mesh, batch)
             for s in ("shard_map", "none")}
     rel = _update_rel_diff(runs["none"][0], runs["shard_map"][1], runs["none"][1])
     log(f"phase 5 engine step shard_map (4 chips) vs none: losses "
         f"{runs['shard_map'][2]} vs {runs['none'][2]}; relative update difference "
         f"{rel!r} (tolerance {UPDATE_RTOL})")
     check(rel <= UPDATE_RTOL, "sharded engine step disagrees with the unsharded one")
-
-    mesh = make_host_mesh(4, 1)
-    runs = {s: _params_after(cfg, tcfg(server=s), mesh, batch)
-            for s in ("sharded", "gather")}
-    rel = _update_rel_diff(runs["gather"][0], runs["sharded"][1], runs["gather"][1])
-    log(f"phase 5 protomath step on a 4x1 data mesh, server sharded (all-to-all) "
-        f"vs gather (all-gather): losses {runs['sharded'][2]} vs {runs['gather'][2]}; "
-        f"relative update difference {rel!r} (tolerance {UPDATE_RTOL})")
-    check(rel <= UPDATE_RTOL, "all-to-all and all-gather servers disagree")
 
 
 def main() -> None:

@@ -1,17 +1,19 @@
 """Quickstart: Byzantine-robust training of a small LM with LAD, on CPU.
 
-Builds a reduced SmolLM-family model on a 4 (data) x 2 (model) virtual mesh,
-marks one of the four logical LAD devices Byzantine (sign-flipping attack),
-and trains with cyclic gradient coding (d=2) + CWTM aggregation.
+Builds a reduced SmolLM-family model, marks one of the four logical LAD
+devices Byzantine (sign-flipping attack), and trains with cyclic gradient
+coding (d=2) + CWTM aggregation through the protocol-engine step.  The four
+subsets' gradients fan out over four virtual CPU devices
+(``shard="shard_map"``), one subset per device, as on a 2x2 TPU host.
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python examples/quickstart.py
 """
 import os
 
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
     )
 
 import jax
@@ -24,17 +26,18 @@ from repro.launch.train import Trainer
 
 
 def main():
-    mesh = make_host_mesh(data=4, model=2)
+    mesh = make_host_mesh(data=1, model=1)
     cfg = reduced(ARCHS["smollm-360m"])
     tcfg = TrainConfig(
         arch=cfg.name,
         protocol="lad",
+        n_subsets=4,              # four logical LAD devices
+        shard="shard_map",        # one subset's gradient per device
         d=2,                      # cyclic gradient-coding redundancy
         aggregator="cwtm",        # kappa-robust server rule
         trim_frac=0.25,
         n_byz=1,                  # one of four devices is Byzantine
         attack="sign_flip",       # Section VII attack (coefficient -2)
-        server="sharded",         # all-to-all sharded server (beyond-paper)
         optimizer="adamw",
         lr=1e-3,
         steps=30,

@@ -47,7 +47,7 @@ def main() -> None:
                         help="run the PR-1 per-scenario dispatch loop instead "
                              "of the vmapped whole-grid engine")
     parser.add_argument("--shard", default="none",
-                        choices=["none", "pmap", "shard_map"],
+                        choices=["none", "shard_map"],
                         help="partition each bucket's scenario-lane axis over "
                              "all visible devices")
     parser.add_argument("--max-lanes-per-device", default=None,

@@ -2,8 +2,7 @@
 
 Uses the reduced RWKV-6 config (O(1) state — the long-context family) and a
 reduced llama-family model side by side, demonstrating the shared serving API
-(prefill -> ring-buffer/state caches -> decode_step) that the dry-run lowers
-for the 32k/500k shapes on the production mesh.
+(prefill -> ring-buffer/state caches -> decode_step).
 
     PYTHONPATH=src python examples/serve_decode.py
 """

@@ -83,7 +83,7 @@ def validate_grid_sharded_json(payload: dict) -> None:
     assert payload.get("schema_version") == GRID_SHARDED_SCHEMA_VERSION, (
         payload.get("schema_version")
     )
-    assert payload.get("shard") in ("pmap", "shard_map"), payload.get("shard")
+    assert payload.get("shard") == "shard_map", payload.get("shard")
     for field in ("device_count", "lanes", "max_lanes_per_device", "steps",
                   "n_devices", "dim"):
         v = payload.get(field)
@@ -131,7 +131,7 @@ def validate_lm_engine_json(payload: dict) -> None:
     assert payload.get("schema_version") == LM_ENGINE_SCHEMA_VERSION, (
         payload.get("schema_version")
     )
-    assert payload.get("shard") in ("pmap", "shard_map"), payload.get("shard")
+    assert payload.get("shard") == "shard_map", payload.get("shard")
     for field in ("device_count", "lanes", "max_lanes_per_device", "steps",
                   "n_devices", "per_subset", "seq_len", "params"):
         v = payload.get(field)

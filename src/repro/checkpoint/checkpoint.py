@@ -98,8 +98,7 @@ def restore_for_serving(path: str, cfg) -> tuple[Any, Any, int]:
     from repro import models
 
     # the logical-spec tree is plain python data produced during tracing —
-    # not a valid eval_shape output — so it rides a side channel (the same
-    # pattern as launch.dryrun._shapes_and_specs)
+    # not a valid eval_shape output — so it rides a side channel
     captured = {}
 
     def only_params(k):

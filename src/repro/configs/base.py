@@ -4,7 +4,7 @@ Every assigned architecture is expressed as an ``ArchConfig``.  Heterogeneous
 stacks (Jamba's 1:7 attn:mamba interleave, Llama-Vision's every-5th cross-attn
 layer) are expressed as a repeating *period*: a short list of block specs that
 is scanned ``n_layers / len(period)`` times.  This keeps the compiled HLO
-size O(period), not O(n_layers) — essential for 100-layer dry-runs.
+size O(period), not O(n_layers).
 
 Block kinds:
   * ``attn``        — GQA self-attention (RoPE), optional sliding window
@@ -135,68 +135,45 @@ class ArchConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # train | prefill | decode
-
-
-INPUT_SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
-
-
-@dataclasses.dataclass(frozen=True)
 class TrainConfig:
     """Run-level configuration: protocol + optimizer + schedule."""
 
     arch: str = "smollm-360m"
-    shape: str = "train_4k"
     # LAD protocol
     protocol: str = "lad"  # lad | plain | none (none = honest mean all-reduce)
-    # Protocol realization:
-    #   "protomath" — per-parameter robust exchange inside the backward pass
-    #                 (custom_vjp rules of core.protomath; the GSPMD-sharded
-    #                 production path: all-to-all / all-gather servers)
-    #   "engine"    — whole-model protocol round via core.byzantine: per-subset
-    #                 gradients are computed explicitly (vmap over the device
-    #                 blocks of the batch), flattened, and pushed through the
-    #                 same assignment -> eq.-(5) encode -> compress -> attack ->
-    #                 robust-aggregate pipeline as the paper's linear-regression
-    #                 runs (Algorithm 1/2 verbatim, incl. the randomized cyclic
-    #                 task matrix that protomath approximates with data rolls)
-    protocol_impl: str = "protomath"
-    # logical LAD device count for the engine path (None: the mesh's data
-    # size); the global batch's leading dim must be divisible by it
+    # Protocol realization; "engine" is the only one: a whole-model protocol
+    # round via core.byzantine — per-subset gradients are computed explicitly
+    # (vmap over the subset blocks of the batch), flattened, and pushed
+    # through the same assignment -> eq.-(5) encode -> compress -> attack ->
+    # robust-aggregate pipeline as the paper's linear-regression runs
+    # (Algorithm 1/2, incl. the randomized cyclic task matrix).
+    # launch.train.build_train_step rejects any other value.
+    protocol_impl: str = "engine"
+    # logical LAD device count (None: the mesh's data size); the global
+    # batch's leading dim must be divisible by it
     n_subsets: int | None = None
-    # Device sharding of the engine path's per-subset gradient fan-out
-    # ("none" | "pmap" | "shard_map" — the grid engine's substrate axis):
-    # the subset axis is padded to a multiple of the engine device count by
-    # replicating the last subset's batch block (launch.mesh contract), each
-    # device computes its subsets' gradients, and the full round body
-    # (assignment -> eq.-(5) encode -> compress -> attack -> aggregate) runs
-    # replicated on the all-gathered (N, P) stack.  Engine-path only: the
-    # protomath realization shards via GSPMD instead and rejects shard!=none.
+    # Device sharding of the per-subset gradient fan-out ("none" |
+    # "shard_map" — the grid engine's substrate axis): the subset axis is
+    # padded to a multiple of the engine device count by replicating the last
+    # subset's batch block (launch.mesh contract), each device computes its
+    # subsets' gradients, and the full round body (assignment -> eq.-(5)
+    # encode -> compress -> attack -> aggregate) runs replicated on the
+    # all-gathered (N, P) stack.  build_train_step rejects any other value.
     shard: str = "none"
     d: int = 2  # computational load (subsets per device)
     aggregator: str = "cwtm"
     trim_frac: float = 0.125
     n_byz: int = 0
     attack: str = "sign_flip"
-    server: str = "sharded"  # sharded (all_to_all) | gather (paper baseline)
     compression: str = "none"  # none | rand_sparse | rand_sparse_shared | quant
     q_hat_frac: float = 0.3
     quant_levels: int = 16
-    # optimizer
-    # gradient accumulation: the local (d-redundant) batch is split into this
-    # many microbatches; the LAD robust exchange runs per microbatch (the
-    # aggregation granularity becomes the micro-round — see DESIGN.md) and the
-    # shard-sized robust gradients are accumulated in fp32.
+    # gradient accumulation: each subset's rows are split into this many
+    # microbatches; the robust exchange runs per microbatch (the aggregation
+    # granularity becomes the micro-round) and the aggregated gradients
+    # average in fp32.
     microbatches: int = 1
+    # optimizer
     optimizer: str = "adamw"
     lr: float = 3e-4
     weight_decay: float = 0.01

@@ -14,7 +14,6 @@ Layout:
                     erasure masks from deterministic key-derived schedules)
   engine.py       — scan-compiled multi-round trajectory engine
   scenarios.py    — declarative method x attack x aggregator x compressor grid
-  distributed.py  — mesh/shard_map production realization of the protocol
   theory.py       — Lemmas 1-4 / Theorems 1-2 constants and error terms
 """
 from repro.core import aggregators, attacks, coding, compression, task_matrix, theory

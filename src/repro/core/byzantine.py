@@ -4,13 +4,10 @@ This module is the *protocol* layer: given per-subset gradients, it performs
 one full round — task assignment, eq.-(5) encoding, compression, Byzantine
 corruption, robust aggregation — and returns the global update direction.
 
-Two execution styles are provided:
-
-  * ``lad_round`` — single-process vectorized simulation over the N logical
-    devices (used by the paper-reproduction benchmarks and the tests, where
-    all N subset gradients are computable in one place);
-  * the sharded shard_map production path lives in ``core/distributed.py``
-    and re-uses the same primitives.
+``protocol_round`` is one vectorized round over the N logical devices' full
+``(N, P)`` gradient stack (``lad_round`` computes that stack from a subset
+gradient function first).  The grid engine (``core/engine.py``) and the LM
+train step (``launch/train.py``) both run it.
 
 ``method``:
   * ``"lad"``   — Algorithm 1/2 (Com-LAD when ``compression.name != 'none'``)

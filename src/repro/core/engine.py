@@ -98,7 +98,7 @@ def make_engine_mesh(axis: str = "lanes") -> Mesh:
     over *every* global device in process-major order — see
     ``engine_device_grid``.  ``_grid_program`` runs its vmapped lane program
     under ``shard_map`` over this mesh, and
-    ``launch.train.build_engine_step`` its subset-gradient fan-out.
+    ``launch.train.build_train_step`` its subset-gradient fan-out.
     """
     return Mesh(engine_device_grid().reshape(-1), (axis,))
 
@@ -668,11 +668,9 @@ def run_grid(
 
         * ``"none"``      — single-device vmap (the default; exactly the
           pre-sharding path);
-        * ``"shard_map"``  — the lane axis is partitioned over every visible
+        * ``"shard_map"`` — the lane axis is partitioned over every visible
           device with ``jax.shard_map`` (each device runs the
-          identical vmapped scan on its lane shard; one jitted program);
-        * ``"pmap"``      — the same partition via ``jax.pmap`` (per-device
-          replica dispatch; kept as the second substrate / cross-check).
+          identical vmapped scan on its lane shard; one jitted program).
 
         Lane counts are padded up to a multiple of ``jax.device_count()``
         by replicating the last lane; padded lanes are sliced off before
@@ -786,8 +784,8 @@ def _plan_grid(
     server_branches = (
         server_branches if server_branches is not None else (make_server_fn(cfg),)
     )
-    if shard not in ("none", "pmap", "shard_map"):
-        raise ValueError(f"unknown shard mode {shard!r}")
+    if shard not in ("none", "shard_map"):
+        raise ValueError(f"unknown shard mode {shard!r}: expected 'none' or 'shard_map'")
     lr_batched = not callable(lr) and getattr(jnp.asarray(lr), "ndim", 0) == 1
     axes_sig = (
         lr_batched,
@@ -931,9 +929,7 @@ def grid_compiled_hlo(
     put a %-of-peak figure next to every scaling-benchmark wall clock.
 
     Same signature as ``run_grid`` (including ``max_lanes_per_device=
-    "auto"``, which resolves through the tuner cache).  ``shard="pmap"`` has
-    no single jitted module to lower (per-device replica dispatch) and is
-    rejected.
+    "auto"``, which resolves through the tuner cache).
     """
     plan = _plan_grid(
         cfg, keys, x0, subset_grad_fn, steps=steps, lr=lr, data=data,
@@ -942,11 +938,6 @@ def grid_compiled_hlo(
         server_ids=server_ids, optimizer=optimizer, grad_scale=grad_scale,
         loss_fn=loss_fn, x_star=x_star, x0_batched=x0_batched, shard=shard,
     )
-    if shard == "pmap":
-        raise ValueError(
-            "grid_compiled_hlo needs a single jitted module; shard='pmap' "
-            "dispatches per-device replicas — lower shard='shard_map' instead"
-        )
     chunk = _resolve_chunk(plan, max_lanes_per_device)
     take = min(chunk, plan.n_lanes)
     ops = plan.chunk_operands(0, take, chunk)
@@ -979,10 +970,9 @@ def _grid_program(
 
     ``shard="shard_map"`` wraps the SAME vmapped lane program in a
     ``shard_map`` over a 1-D ``("lanes",)`` device mesh (lane-carrying
-    operands partitioned, shared operands replicated); ``shard="pmap"``
-    reshapes the lane axis to ``(devices, lanes_per_device)`` and dispatches
-    per-device replicas.  Both reuse ``one_lane`` verbatim, which is what
-    keeps sharded lanes bitwise equal to the unsharded grid.
+    operands partitioned, shared operands replicated).  It reuses
+    ``one_lane`` verbatim, which is what keeps sharded lanes bitwise equal
+    to the unsharded grid.
     """
     (lr_batched, has_attack_ids, has_server_ids, data_batched,
      x0_batched, has_x_star) = axes_sig
@@ -1033,42 +1023,23 @@ def _grid_program(
     if shard == "none":
         return jax.jit(vmapped)
 
-    if shard == "shard_map":
-        mesh = make_engine_mesh("lanes")
-        in_specs = tuple(
-            PartitionSpec("lanes") if ax == 0 else PartitionSpec()
-            for ax in in_axes
+    mesh = make_engine_mesh("lanes")
+    in_specs = tuple(
+        PartitionSpec("lanes") if ax == 0 else PartitionSpec()
+        for ax in in_axes
+    )
+    # check_vma off: every output is lane-partitioned, there is nothing
+    # replicated for the static checker to prove — and the checker has no
+    # rules for some of the primitives the round body uses
+    return jax.jit(
+        jax.shard_map(
+            vmapped,
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=PartitionSpec("lanes"),
+            check_vma=False,
         )
-        # check_vma off: every output is lane-partitioned, there is nothing
-        # replicated for the static checker to prove — and the checker has no
-        # rules for some of the primitives the round body uses
-        return jax.jit(
-            jax.shard_map(
-                vmapped,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=PartitionSpec("lanes"),
-                check_vma=False,
-            )
-        )
-
-    # shard == "pmap": per-device replica dispatch of the same lane program.
-    devs = engine_device_count()
-    pm = jax.pmap(vmapped, in_axes=in_axes)
-
-    def grid(*args):
-        split = tuple(
-            jax.tree.map(
-                lambda v: v.reshape((devs, v.shape[0] // devs) + v.shape[1:]), a
-            )
-            if ax == 0
-            else a
-            for a, ax in zip(args, in_axes)
-        )
-        out = pm(*split)
-        return jax.tree.map(lambda v: v.reshape((-1,) + v.shape[2:]), out)
-
-    return grid
+    )
 
 
 def protocol_rounds(

@@ -538,7 +538,7 @@ def run_grid(
     XLA bucket: zero per-scenario dispatches on a warm sweep, every lane
     bitwise equal to its standalone trajectory.
 
-    ``shard="pmap"``/``"shard_map"`` partitions every compile bucket's lane
+    ``shard="shard_map"`` partitions every compile bucket's lane
     axis over the visible devices (lane counts padded to a device multiple;
     see ``engine.run_grid``), and ``max_lanes_per_device`` streams a large
     bucket through equal-sized chunks of one cached program — together they
@@ -808,7 +808,7 @@ def _lm_fns(arch):
     ``subset_grad_fn`` unflattens it, computes every subset's full-model
     gradient (``jax.vmap`` over the N data subsets) and flattens each back to
     a row of the ``(N, P)`` stack ``protocol_round`` consumes — exactly the
-    ``launch.train.build_engine_step`` pipeline, realized as a grid lane.
+    ``launch.train.build_train_step`` pipeline, realized as a grid lane.
     lru-cached so the returned callables have stable identities: they key the
     engine's compiled-program cache (zero warm compiles across sweeps).
     (maxsize covers the whole ``ZOO_FAMILIES`` zoo at once.)
@@ -1008,7 +1008,7 @@ def run_lm_grid(
     two share ``_run_bucket`` via the ``_BucketProblem`` adapter).
 
     Every lane is bitwise equal to its standalone ``run_lm_scenario``
-    trajectory, and ``shard="pmap"|"shard_map"`` to the unsharded grid, at
+    trajectory, and ``shard="shard_map"`` to the unsharded grid, at
     the clean simulation scales (N = 10/16/32) — asserted by
     tests/test_train_engine_shard.py on 1 device in tier-1 and on 8 forced
     host devices in CI.  All rows must share one heterogeneity level

@@ -3,8 +3,8 @@
 ``backend`` selects the implementation:
   * ``"pallas"``      — compiled Pallas (TPU target)
   * ``"interpret"``   — Pallas interpret mode (CPU-correct; used by tests)
-  * ``"xla"``         — the pure-jnp oracle (default inside the production
-                        step functions so CPU dry-runs lower everywhere)
+  * ``"xla"``         — the pure-jnp oracle (the default: lowers on every
+                        backend)
 
 The wrappers own the tiling contract: callers may pass ANY ``Q`` — when the
 length does not divide the tile, inputs are zero-padded up to the next tile

@@ -1,18 +1,16 @@
-"""Mesh construction: the production GSPMD meshes and the engine's 1-D mesh.
+"""Mesh construction: the trainer's host mesh and the engine's 1-D mesh.
 
 Two mesh families are exposed here:
 
-* **Production meshes** (``make_production_mesh`` / ``make_host_mesh``) — the
-  ("data", "model") / ("pod", "data", "model") GSPMD meshes of the protomath
-  train path.  Single pod: 16 x 16 = 256 chips; multi-pod: 2 x 16 x 16 = 512
-  chips with the ``pod`` axis extending the Byzantine/data-parallel domain
-  across the DCN/ICI boundary.
+* **Host meshes** (``make_host_mesh``) — the ("data", "model") /
+  ("pod", "data", "model") meshes ``Trainer`` places parameters and batches
+  on; the data axes' size is the step's default subset count.
 
 * **Engine meshes** (``make_engine_mesh`` + ``engine_device_grid`` /
   ``engine_device_count`` / ``padded_lane_count``) — the 1-D named device
   mesh the protocol-engine paths shard over: ``core.engine.run_grid``
   partitions its scenario-*lane* axis over it, and
-  ``launch.train.build_engine_step`` (``TrainConfig.shard``) its LM *subset*
+  ``launch.train.build_train_step`` (``TrainConfig.shard``) its LM *subset*
   fan-out.  These are defined in ``core.engine`` (beside ``pad_lanes``, the
   replication half of the same padding contract, keeping the core -> launch
   dependency arrow one-way) and re-exported here as the deployment-layer
@@ -26,9 +24,7 @@ device list, not the sharding or padding/replication contract.  Today every
 caller is single-process.
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state; the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any jax
-import and only then calls these.
+never touches jax device state.
 """
 from __future__ import annotations
 
@@ -46,16 +42,10 @@ from repro.core.engine import (  # noqa: F401  (re-exported deployment API)
 
 
 def _auto_mesh(shape, axes):
-    """``jax.make_mesh`` with every axis ``Auto``: the GSPMD train path relies
-    on sharding propagation, which the default ``Explicit`` axis types turn
-    into per-op ``out_sharding`` requirements (e.g. on the embedding gather)."""
+    """``jax.make_mesh`` with every axis ``Auto``: placement relies on sharding
+    propagation, which the default ``Explicit`` axis types turn into per-op
+    ``out_sharding`` requirements (e.g. on the embedding gather)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int | None = None):

@@ -1,16 +1,15 @@
-"""Roofline-term extraction from compiled dry-run artifacts.
+"""Scan-aware roofline analysis of compiled programs.
 
-Three terms per (arch x shape x mesh), all in seconds, per the assignment:
+``analyze_compiled`` reads one optimized HLO module (``compiled.as_text()``)
+and predicts its runtime on a device from three terms, all in seconds:
 
-    compute    = HLO_FLOPs / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes / (chips * HBM_BW)
-    collective = sum over collective ops of wire-byte cost / LINK_BW
+    compute    = HLO_FLOPs / peak_flops
+    memory     = HLO_bytes / mem_bw
+    collective = sum over collective ops of wire-byte cost / link_bw
 
-``cost_analysis()`` on an SPMD-compiled module reports *per-partition* flops
-and bytes, so chips-normalization is already done — we use them directly as
-per-chip quantities.  Collective bytes are parsed from the optimized HLO
-(``compiled.as_text()``), whose shapes are also per-partition; per-op wire
-coefficients follow the standard ring/bidirectional-exchange costs:
+Shapes in SPMD-compiled HLO are per-partition, so every term is per chip.
+Per-op wire coefficients follow the standard ring/bidirectional-exchange
+costs:
 
     all-gather        result_bytes           (each chip receives the gathered copy)
     reduce-scatter    operand_bytes
@@ -18,13 +17,12 @@ coefficients follow the standard ring/bidirectional-exchange costs:
     all-to-all        operand_bytes
     collective-permute result_bytes
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+``percent_of_peak`` sets that prediction against a measured wall clock.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from collections import defaultdict
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -64,52 +62,6 @@ def _type_bytes(type_str: str) -> int:
                 n *= int(d)
         total += n * _DTYPE_BYTES[dtype]
     return total
-
-
-def parse_collectives(hlo_text: str) -> dict:
-    """Scan optimized HLO; returns per-op-kind wire bytes + counts (per chip)."""
-    shape_of: dict[str, int] = {}
-    per_kind_bytes: dict[str, float] = defaultdict(float)
-    per_kind_count: dict[str, int] = defaultdict(int)
-
-    for line in hlo_text.splitlines():
-        m = _INSTR_RE.match(line)
-        if not m:
-            continue
-        name, type_str, op = m.group("name"), m.group("type"), m.group("op")
-        rb = _type_bytes(type_str)
-        shape_of[name] = rb
-        kind = None
-        for k in COLLECTIVE_OPS:
-            if op == k or op.startswith(k + "-start") or op == k + "-start":
-                kind = k
-                break
-        if kind is None:
-            continue
-        # operand bytes: resolve %name references in the argument list
-        operand_bytes = 0
-        for ref in re.findall(r"%([\w.\-]+)", m.group("args")):
-            operand_bytes += shape_of.get(ref, 0)
-        if operand_bytes == 0:
-            operand_bytes = rb  # fallback: assume same-size operand
-        if kind == "all-gather":
-            wire = rb
-        elif kind == "all-reduce":
-            wire = 2 * rb
-        elif kind == "reduce-scatter":
-            wire = operand_bytes
-        elif kind == "all-to-all":
-            wire = operand_bytes
-        else:  # collective-permute
-            wire = rb
-        per_kind_bytes[kind] += wire
-        per_kind_count[kind] += 1
-
-    return {
-        "bytes_by_kind": dict(per_kind_bytes),
-        "count_by_kind": dict(per_kind_count),
-        "total_wire_bytes": float(sum(per_kind_bytes.values())),
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -350,54 +302,12 @@ def _prod(dims) -> float:
     return p
 
 
-@dataclasses.dataclass
-class RooflineTerms:
-    flops_per_chip: float
-    bytes_per_chip: float
-    wire_bytes_per_chip: float
-    compute_s: float
-    memory_s: float
-    collective_s: float
-    dominant: str
-    model_flops_per_chip: float = 0.0
-    useful_ratio: float = 0.0
-
-    def as_dict(self):
-        return dataclasses.asdict(self)
-
-
-def derive_terms(
-    cost: dict, collectives: dict, model_flops_total: float = 0.0, chips: int = 1
-) -> RooflineTerms:
-    flops = float(cost.get("flops", 0.0))
-    byts = float(cost.get("bytes accessed", 0.0))
-    wire = float(collectives.get("total_wire_bytes", 0.0))
-    compute_s = flops / PEAK_FLOPS
-    memory_s = byts / HBM_BW
-    collective_s = wire / LINK_BW
-    terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
-    dominant = max(terms, key=terms.get)
-    mf_chip = model_flops_total / max(chips, 1)
-    return RooflineTerms(
-        flops_per_chip=flops,
-        bytes_per_chip=byts,
-        wire_bytes_per_chip=wire,
-        compute_s=compute_s,
-        memory_s=memory_s,
-        collective_s=collective_s,
-        dominant=dominant,
-        model_flops_per_chip=mf_chip,
-        useful_ratio=(mf_chip / flops) if flops > 0 else 0.0,
-    )
-
-
 # ---------------------------------------------------------------------------
 # %-of-peak for engine programs (the benchmark-row wiring)
 # ---------------------------------------------------------------------------
-# The dry-run path above targets the TPU v5e constants; the engine benches
-# analyze compiled programs for whatever device runs them, so the roofline
-# keys its peaks by ``jax.devices()[0].device_kind``.  A device missing here
-# is an error, never a default.
+# The engine benches analyze compiled programs for whatever device runs
+# them, so the roofline keys its peaks by ``jax.devices()[0].device_kind``.
+# A device missing here is an error, never a default.
 #
 #   "TPU v5 lite" (TPU v5e): Google Cloud documentation, "TPU v5e" —
 #       197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI (4 links x 50 GB/s).
@@ -475,40 +385,3 @@ def percent_of_peak(
     if measured_s <= 0:
         raise ValueError(f"measured_s must be > 0, got {measured_s}")
     return max(0.0, 100.0 * analysis["predicted_s"] * calls / measured_s)
-
-
-# ---------------------------------------------------------------------------
-# Analytic MODEL_FLOPS (the 6ND / 2ND yardstick)
-# ---------------------------------------------------------------------------
-def active_params(cfg) -> int:
-    """Total params counted with only top_k of n_experts active per MoE layer."""
-    import jax
-
-    from repro import models
-    from repro.models.module import tree_size
-
-    shapes = jax.eval_shape(lambda k: models.init(k, cfg)[0], jax.random.PRNGKey(0))
-    total = tree_size(jax.tree.leaves(shapes))
-    if cfg.moe is None:
-        return total
-    # subtract the inactive expert fraction of expert weights
-    expert = 0
-    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
-        keys = [str(getattr(p, "key", "")) for p in path]
-        if any(k in ("w_gate", "w_up", "w_down") for k in keys) and leaf.ndim >= 3:
-            expert += int(leaf.size)
-    inactive_frac = 1.0 - cfg.moe.top_k / cfg.moe.n_experts
-    return int(total - expert * inactive_frac)
-
-
-def model_flops(cfg, shape, n_active: int | None = None, d_redundancy: int = 1) -> float:
-    """6*N*D for a train step (x d for LAD redundancy), 2*N*D per served token."""
-    n_act = n_active if n_active is not None else active_params(cfg)
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n_act * tokens * d_redundancy
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n_act * tokens
-    # decode: one token per sequence
-    return 2.0 * n_act * shape.global_batch

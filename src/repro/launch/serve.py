@@ -1,94 +1,17 @@
-"""Serving steps (prefill / decode) under pjit with explicit cache shardings.
+"""Serving steps (prefill / decode) and one batch of measured traffic.
 
 Inference carries no gradient exchange, so the LAD protocol is inactive here;
-the paper's technique is train-time.  The serving path exists because the
-assigned input shapes include prefill and decode workloads — the roofline of
-these shapes characterizes the model substrate itself.
-
-Cache sharding policy (decided per-leaf from divisibility):
-  * batch dim        -> data axes when divisible (decode_32k: 128/16)
-  * else KV sequence -> data axes (long_500k: batch 1, 512k cache rows)
-  * heads / d_inner  -> model axis when divisible
+the paper's technique is train-time.  The serving path closes the
+train-to-serve loop: a ``Trainer`` checkpoint restores straight into these
+jitted prefill/decode fns (``repro.checkpoint.restore_for_serving``).
 """
 from __future__ import annotations
 
-from typing import Any
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import models
-from repro.configs.base import ArchConfig, ShapeConfig
-from repro.launch.mesh import data_axes, n_data_devices
-
-
-def _dax(mesh):
-    axes = data_axes(mesh)
-    return axes if len(axes) > 1 else axes[0]
-
-
-def _div(n: int, mesh, axis) -> bool:
-    import math
-
-    if isinstance(axis, tuple):
-        size = math.prod(mesh.shape[a] for a in axis)
-    else:
-        size = mesh.shape[axis]
-    return n % size == 0 and n >= size
-
-
-def decode_state_pspecs(state_shapes: Any, mesh) -> Any:
-    """PartitionSpec tree for a decode state (leaves carry leading period dim)."""
-    dax = _dax(mesh)
-
-    def leaf_spec(path, leaf):
-        names = [getattr(p, "name", getattr(p, "key", "")) for p in path]
-        field = names[-1] if names else ""
-        shp = leaf.shape
-        if field in ("k", "v"):  # (P, B, C, Hkv, Dh)
-            _, b, c, h, hd = shp
-            # batch over data; cache rows (sequence) over model when the
-            # (usually indivisible) kv-head count cannot shard — flash-decode
-            # style: each model shard attends to its slice of the context and
-            # GSPMD combines the partial softmax stats.  This is what makes
-            # 32k x 128-seq caches of the 90B+ models fit 16 GB chips.
-            h_ax = "model" if _div(h, mesh, "model") else None
-            c_ax = None if h_ax else ("model" if _div(c, mesh, "model") else None)
-            if _div(b, mesh, dax):
-                return P(None, dax, c_ax, h_ax, None)
-            if _div(c, mesh, dax):
-                return P(None, None, dax, h_ax, None)
-            return P(None, None, c_ax, h_ax, None)
-        if field == "length":
-            return P(None)
-        if field == "pos":  # scalar decode position counter
-            return P()
-        if field == "h":  # mamba (P, B, di, ds)
-            _, b, di, _ = shp
-            return P(None, dax if _div(b, mesh, dax) else None,
-                     "model" if _div(di, mesh, "model") else None, None)
-        if field == "conv":  # (P, B, k-1, di)
-            _, b, _, di = shp
-            return P(None, dax if _div(b, mesh, dax) else None, None,
-                     "model" if _div(di, mesh, "model") else None)
-        if field == "wkv":  # (P, B, H, hd, hd)
-            _, b, h, _, _ = shp
-            return P(None, dax if _div(b, mesh, dax) else None,
-                     "model" if _div(h, mesh, "model") else None, None, None)
-        if field in ("x_prev", "ffn_x_prev"):  # (P, B, D)
-            _, b, d = shp
-            return P(None, dax if _div(b, mesh, dax) else None,
-                     "model" if _div(d, mesh, "model") else None)
-        # fallback: replicate
-        return P(*([None] * len(shp)))
-
-    return jax.tree_util.tree_map_with_path(leaf_spec, state_shapes)
-
-
-def batch_dim_pspec(n: int, mesh) -> P:
-    dax = _dax(mesh)
-    return P(dax) if _div(n, mesh, dax) else P(None)
+from repro.configs.base import ArchConfig
 
 
 def build_decode_fn(cfg: ArchConfig, mesh, param_shardings, specs):
@@ -161,40 +84,3 @@ def serve_traffic(
         "tokens": jnp.concatenate(out, axis=1),
         "pos": int(state["pos"]),
     }
-
-
-def serve_input_specs(cfg: ArchConfig, shape: ShapeConfig, mesh):
-    """ShapeDtypeStructs (with shardings) for the serve inputs of ``shape``."""
-    b = shape.global_batch
-    dax = _dax(mesh)
-    bspec = batch_dim_pspec(b, mesh)
-
-    def sds(shp, dtype, pspec):
-        return jax.ShapeDtypeStruct(shp, dtype, sharding=NamedSharding(mesh, pspec))
-
-    if shape.kind == "prefill":
-        out = {
-            "tokens": sds((b, shape.seq_len), jnp.int32, P(bspec[0], None)),
-        }
-        if cfg.family in ("vlm", "audio"):
-            enc = cfg.encoder
-            out["frontend"] = sds(
-                (b, enc.n_frontend_tokens, enc.d_frontend), jnp.float32,
-                P(bspec[0], None, None),
-            )
-        return out
-    if shape.kind == "decode":
-        state_shapes = jax.eval_shape(
-            lambda: models.init_decode_state(cfg, b, shape.seq_len)
-        )
-        pspecs = decode_state_pspecs(state_shapes, mesh)
-        state = jax.tree.map(
-            lambda s, ps: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                               sharding=NamedSharding(mesh, ps)),
-            state_shapes, pspecs,
-        )
-        return {
-            "token": sds((b, 1), jnp.int32, P(bspec[0], None)),
-            "state": state,
-        }
-    raise ValueError(shape.kind)

@@ -1,47 +1,25 @@
-"""The LAD train step + training driver — two protocol realizations.
+"""The LAD train step and the training driver.
 
-``build_train_step`` assembles one of two full training steps, selected by
-``TrainConfig.protocol_impl``:
+``build_train_step`` is the protocol-engine step: the LM workload runs
+through core.byzantine's ``protocol_round``, i.e. the assignment -> eq.-(5)
+encode -> compress -> attack -> robust-aggregate pipeline of the paper's
+linear-regression experiments, at whole-model granularity.  Per-subset
+gradients are computed explicitly (``jax.vmap`` over the N subset blocks of
+the batch), flattened to an ``(N, P)`` stack, aggregated by the protocol
+(Algorithm 1/2, including the per-round randomized cyclic task matrix), and
+unflattened into the optimizer.  The round selects each device's assigned
+rows of the stack rather than gathering an ``(N, d, P)`` copy.
 
-``"protomath"`` — the pure pjit/GSPMD production step:
-
-  1. cyclic microbatch redundancy — ``d``-fold replication of the device-
-     blocked batch via rolls over the (data-sharded) device axis; GSPMD
-     lowers the rolls to collective-permutes, realizing the cyclic task
-     matrix S_hat on the wire,
-  2. forward/backward under ``protocol_context`` (core.protomath): every
-     parameter's cotangent is computed per-device-block, compressed,
-     Byzantine-corrupted and robustly aggregated (the paper's server),
-  3. ZeRO optimizer update on (data x model)-sharded params/state.
-
-  Everything is GSPMD-sharded from the parameter/batch shardings; there is
-  no shard_map — the protocol lives in the custom_vjp rules of protomath.
-
-``"engine"`` — the protocol-engine step (``build_engine_step``): the LM
-  workload runs through core.byzantine's ``protocol_round``, i.e. *exactly*
-  the assignment -> eq.-(5) encode -> compress -> attack -> robust-aggregate
-  pipeline of the paper's linear-regression experiments, at whole-model
-  granularity.  Per-subset gradients are computed explicitly (``jax.vmap``
-  over the N device blocks of the batch), flattened to an ``(N, P)`` stack,
-  aggregated by the protocol, and unflattened into the optimizer.  This is
-  Algorithm 1/2 verbatim — including the per-round randomized cyclic task
-  matrix, which the protomath path only approximates with deterministic data
-  rolls — making the transformer LM directly comparable to the Section-VII
-  scenario grid.  It materializes an (N, d, P) gather, so it is the
-  simulation/verification path for small-to-mid models, not the
-  production-scale step.
-
-  ``TrainConfig.shard`` partitions the engine step's per-subset gradient
-  fan-out over the engine device mesh (``launch.mesh.make_engine_mesh``):
-  ``"shard_map"`` (one jitted program; the production substrate) or
-  ``"pmap"`` (per-device replica dispatch; the cross-check substrate).  The
-  subset axis is padded to a device multiple by replicating the last
-  subset's batch block (``core.engine.pad_lanes`` — the grid engine's lane
-  contract), each device computes its subsets' gradients, and the full
-  round body runs replicated on the all-gathered, padding-sliced ``(N, P)``
-  stack — so sharded steps are BITWISE equal to ``shard="none"`` at the
-  clean simulation scales (N = 10/16/32; see README "Engine guarantees" and
-  tests/test_train_engine_shard.py).
+``TrainConfig.shard`` partitions the per-subset gradient fan-out over the
+engine device mesh (``launch.mesh.make_engine_mesh``): ``"none"`` (one
+device) or ``"shard_map"`` (one jitted program over every device).  The
+subset axis is padded to a device multiple by replicating the last subset's
+batch block (``core.engine.pad_lanes`` — the grid engine's lane contract),
+each device computes its subsets' gradients, and the full round body runs
+replicated on the all-gathered, padding-sliced ``(N, P)`` stack — so sharded
+steps are BITWISE equal to ``shard="none"`` at the clean simulation scales
+(N = 10/16/32; see README "Engine guarantees" and
+tests/test_train_engine_shard.py).
 """
 from __future__ import annotations
 
@@ -61,7 +39,6 @@ from repro.core.byzantine import ProtocolConfig, protocol_round
 from repro.core.coding import flatten_pytree, unflatten_pytree
 from repro.core import engine as engine_lib
 from repro.core.engine import pad_lanes
-from repro.core.protomath import BlockedProtocol, protocol_context
 from repro.launch.mesh import (
     data_axes,
     engine_device_count,
@@ -72,26 +49,7 @@ from repro.launch.mesh import (
 from repro.models.module import logical_to_mesh
 from repro.numerics import stable_mean0
 from repro.optim import make_optimizer
-from repro.optim.optimizers import OptState
 from repro.optim.schedule import linear_warmup_cosine
-
-
-def make_protocol(tcfg: TrainConfig, mesh) -> BlockedProtocol:
-    axes = data_axes(mesh)
-    return BlockedProtocol(
-        n_devices=n_data_devices(mesh),
-        data_axes=axes,
-        aggregator=tcfg.aggregator,
-        trim_frac=tcfg.trim_frac,
-        n_byz=tcfg.n_byz,
-        attack=attack_lib.AttackSpec(name=tcfg.attack, n_byz=tcfg.n_byz),
-        compression=comp_lib.spec_from(
-            tcfg.compression, q_hat_frac=tcfg.q_hat_frac, levels=tcfg.quant_levels
-        ),
-        server=tcfg.server,
-        honest_mean=(tcfg.protocol == "none"),
-        model_size=mesh.shape.get("model", 1),
-    )
 
 
 def make_round_config(tcfg: TrainConfig, n_subsets: int) -> ProtocolConfig:
@@ -122,7 +80,7 @@ def make_round_config(tcfg: TrainConfig, n_subsets: int) -> ProtocolConfig:
     )
 
 
-# Compiled engine-step programs, cached across build_engine_step calls.
+# Compiled engine-step programs, cached across build_train_step calls.
 # Each program is keyed on exactly the config it reads — (arch cfg, lowered
 # ProtocolConfig, remat, shard substrate, device count) for the round
 # program; (optimizer, momentum dtype, lr, steps, weight decay) for the
@@ -163,7 +121,7 @@ def _build_round_program(cfg, pcfg, remat, n_sub, shard, devs, specs):
     """The fan-out + protocol-round program of one engine-step configuration.
 
     ``(params, blocks, key) -> (loss, metrics, g_flat)`` where ``blocks`` is
-    the ``(N, rows, ...)`` subset-blocked (micro)batch.  All three substrates
+    the ``(N, rows, ...)`` subset-blocked (micro)batch.  Both substrates
     share ``one`` (the per-subset gradient) and ``finalize`` (the round body
     + fixed-tree metric means) verbatim — that sharing is what keeps sharded
     steps bitwise equal to ``shard="none"`` at the clean scales.
@@ -213,35 +171,21 @@ def _build_round_program(cfg, pcfg, remat, n_sub, shard, devs, specs):
                                         gather(stack))
         return finalize(losses, metricses, stack, k)
 
-    if shard == "shard_map":
-        inner = jax.shard_map(
-            per_device,
-            mesh=make_engine_mesh(_SUBSET_AXIS),
-            in_specs=(P(), P(_SUBSET_AXIS), P()),
-            out_specs=(P(), P(), P()),
-            # every output is replicated by construction (post-all-gather);
-            # check_vma has no rules for some round-body primitives
-            check_vma=False,
-        )
+    inner = jax.shard_map(
+        per_device,
+        mesh=make_engine_mesh(_SUBSET_AXIS),
+        in_specs=(P(), P(_SUBSET_AXIS), P()),
+        out_specs=(P(), P(), P()),
+        # every output is replicated by construction (post-all-gather);
+        # check_vma has no rules for some round-body primitives
+        check_vma=False,
+    )
 
-        @jax.jit
-        def round_shard_map(params, blocks, k):
-            return inner(params, pad_lanes(blocks, n_pad - n_sub), k)
+    @jax.jit
+    def round_shard_map(params, blocks, k):
+        return inner(params, pad_lanes(blocks, n_pad - n_sub), k)
 
-        return round_shard_map
-
-    # shard == "pmap": per-device replica dispatch of the same per_device body
-    pm = jax.pmap(per_device, axis_name=_SUBSET_AXIS, in_axes=(None, 0, None))
-
-    def round_pmap(params, blocks, k):
-        padded = pad_lanes(blocks, n_pad - n_sub)
-        split = jax.tree.map(
-            lambda v: v.reshape((devs, n_pad // devs) + v.shape[1:]), padded
-        )
-        out = pm(params, split, k)
-        return jax.tree.map(lambda v: v[0], out)  # replicated: any replica
-
-    return round_pmap
+    return round_shard_map
 
 
 def _engine_round_program(cfg, tcfg, n_sub, specs):
@@ -263,9 +207,9 @@ def _engine_apply_program(tcfg):
     """The cached optimizer-apply program ``(params, opt_state, g_flat, t) ->
     (new_params, new_opt_state)``.
 
-    One jitted program shared by every substrate: the round program's outputs
-    are materialized program outputs (never re-fused into the optimizer
-    math), so all three shard modes step through the exact same apply
+    One jitted program shared by both substrates: the round program's
+    outputs are materialized program outputs (never re-fused into the
+    optimizer math), so both shard modes step through the exact same apply
     compilation — the second half of the substrate-parity guarantee.
     """
     # keyed on the fields apply actually reads, NOT the whole tcfg: every
@@ -295,12 +239,11 @@ def _engine_apply_program(tcfg):
     return prog
 
 
-def build_engine_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
+def build_train_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
     """The protocol-engine train step: LM gradients through ``protocol_round``.
 
-    Returns ``(step_fn, optimizer)`` with the same
-    ``step(params, opt_state, batch, idx)`` signature as the protomath step,
-    so ``Trainer`` drives either transparently.  Per microbatch:
+    Returns ``(step_fn, optimizer)``; ``step(params, opt_state, batch, idx)``
+    returns ``(params, opt_state, loss, metrics)``.  Per microbatch:
 
       1. the global batch's leading dim is blocked into ``N = n_subsets``
          logical LAD devices (``tcfg.n_subsets`` or the mesh's data size);
@@ -317,28 +260,24 @@ def build_engine_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
       4. the aggregated flat gradient un-flattens into the optimizer step.
 
     With ``microbatches > 1`` the robust exchange runs once per microbatch
-    (the aggregation granularity of the protomath path) and the aggregated
-    gradients average in fp32.
+    and the aggregated gradients average in fp32.
 
-    The step is *self-dispatching* (``step.self_dispatching``): it composes
-    two cached compiled programs — the fan-out + round program (per shard
-    substrate) and the shared optimizer-apply program — rather than being
-    one traceable function, so callers must NOT wrap it in ``jax.jit``
-    (re-tracing would inline and re-fuse across the program boundary that
-    keeps the substrates bitwise-comparable; ``Trainer`` checks the flag).
-    Programs are cached across ``build_engine_step`` calls on the static
-    config, so a warm step — and a second step fn built from an equal
-    config — makes zero compiles (``engine_program_cache_info``).  The
-    cached programs deliberately do NOT donate params/opt_state (the old
-    jitted step did): they are shared across callers that may reuse their
-    inputs (conformance tests re-step from one params tree), and this is
-    the small-to-mid-model simulation path, not the memory-bound production
-    step.
+    The step dispatches two cached compiled programs — the fan-out + round
+    program (per shard substrate) and the shared optimizer-apply program —
+    rather than being one traceable function, so callers must NOT wrap it in
+    ``jax.jit`` (re-tracing would inline and re-fuse across the program
+    boundary that keeps the substrates bitwise-comparable).  Programs are
+    cached across ``build_train_step`` calls on the static config, so a warm
+    step — and a second step fn built from an equal config — makes zero
+    compiles (``engine_program_cache_info``).  The cached programs do NOT
+    donate params/opt_state: they are shared across callers that may reuse
+    their inputs (conformance tests re-step from one params tree).
     """
-    if tcfg.shard not in ("none", "pmap", "shard_map"):
+    if tcfg.protocol_impl != "engine":
+        raise ValueError(f"unknown protocol_impl {tcfg.protocol_impl!r}: expected 'engine'")
+    if tcfg.shard not in ("none", "shard_map"):
         raise ValueError(
-            f"unknown engine shard mode {tcfg.shard!r}: expected 'none', "
-            "'pmap' or 'shard_map'"
+            f"unknown engine shard mode {tcfg.shard!r}: expected 'none' or 'shard_map'"
         )
     n_sub = tcfg.n_subsets or n_data_devices(mesh)
     opt = make_optimizer(tcfg.optimizer, momentum_dtype=tcfg.momentum_dtype)
@@ -359,7 +298,7 @@ def build_engine_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
         def to_engine(tree):
             return jax.device_put(tree, _rep)
 
-    else:  # "none" shares the caller's placement; pmap replicates itself
+    else:  # "none" shares the caller's placement
         def to_engine(tree):
             return tree
 
@@ -405,7 +344,6 @@ def build_engine_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
             new_params, new_opt = apply_prog(params, opt_state, g_flat, step_idx)
         return new_params, new_opt, loss, metrics
 
-    step.self_dispatching = True
     return step, opt
 
 
@@ -431,113 +369,6 @@ def batch_pspec(mesh, extra_dims: int = 1) -> P:
     return P(lead, *([None] * extra_dims))
 
 
-def redundant_batch(batch: Any, d: int, n_devices: int) -> Any:
-    """Cyclic gradient-coding redundancy in the global view.
-
-    The batch's leading dim is device-blocked ``(N * b, ...)``; device ``i``
-    must additionally compute subsets ``i+1 .. i+d-1`` (cyclic task matrix).
-    Rolling the device-block axis by -j hands block ``i`` block ``i+j``'s
-    data; GSPMD lowers the roll over the data-sharded axis to a
-    collective-permute ring — the redundancy traffic of LAD.
-    """
-    if d <= 1:
-        return batch
-
-    def leaf(x):
-        blocks = x.reshape((n_devices, x.shape[0] // n_devices) + x.shape[1:])
-        rolled = [jnp.roll(blocks, -j, axis=0) for j in range(d)]
-        out = jnp.concatenate(rolled, axis=1)  # (N, d*b, ...)
-        return out.reshape((x.shape[0] * d,) + x.shape[1:])
-
-    return jax.tree.map(leaf, batch)
-
-
-def build_train_step(cfg: ArchConfig, tcfg: TrainConfig, mesh, specs):
-    """Returns (step_fn, optimizer).  step(params, opt_state, batch, idx).
-
-    ``tcfg.protocol_impl`` selects the realization: ``"protomath"`` (default,
-    the GSPMD per-parameter exchange below) or ``"engine"`` (whole-model
-    ``protocol_round`` — see ``build_engine_step``).
-    """
-    if tcfg.protocol_impl == "engine":
-        return build_engine_step(cfg, tcfg, mesh, specs)
-    if tcfg.protocol_impl != "protomath":
-        raise ValueError(f"unknown protocol_impl {tcfg.protocol_impl!r}")
-    if tcfg.shard != "none":
-        raise ValueError(
-            f"shard={tcfg.shard!r} is an engine-path option "
-            "(protocol_impl='engine'); the protomath realization is GSPMD-"
-            "sharded by its parameter/batch shardings and takes no shard="
-        )
-    n_dev = n_data_devices(mesh)
-    protocol = make_protocol(tcfg, mesh)
-    opt = make_optimizer(tcfg.optimizer, momentum_dtype=tcfg.momentum_dtype)
-    schedule = linear_warmup_cosine(tcfg.lr, warmup=max(tcfg.steps // 20, 1),
-                                    total_steps=tcfg.steps)
-    d = 1 if tcfg.protocol == "none" else tcfg.d
-    base_key = jax.random.PRNGKey(tcfg.seed)
-    bspec = batch_pspec(mesh)
-
-    def step(params, opt_state, batch, step_idx):
-        round_key = jax.random.fold_in(base_key, step_idx)
-        batch_d = redundant_batch(batch, d, n_dev)
-        m = tcfg.microbatches
-
-        def loss_and_grad(mb, mb_key):
-            with protocol_context(protocol, mb_key):
-                def loss_fn(pp):
-                    return models.loss_fn(pp, specs, cfg, mb, remat=tcfg.remat)
-
-                return jax.value_and_grad(loss_fn, has_aux=True)(params)
-
-        if m <= 1:
-            (loss, metrics), grads = loss_and_grad(batch_d, round_key)
-        else:
-            # microbatch split within each device block: every microbatch
-            # keeps the (N, sl) device-block layout the protocol needs
-            db = batch_d["tokens"].shape[0] // n_dev  # rows per device block
-            assert db % m == 0, (db, m)
-            sl = db // m
-
-            def micro_slice(x, j):
-                blocks = x.reshape((n_dev, db) + x.shape[1:])
-                piece = jax.lax.dynamic_slice_in_dim(blocks, j * sl, sl, axis=1)
-                return piece.reshape((n_dev * sl,) + x.shape[1:])
-
-            def micro_step(acc, j):
-                mb = jax.tree.map(lambda x: micro_slice(x, j), batch_d)
-                (l, met), g = loss_and_grad(mb, jax.random.fold_in(round_key, j))
-                acc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), acc, g)
-                return acc, (l, met)
-
-            acc0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            grads, (losses, metricses) = jax.lax.scan(
-                micro_step, acc0, jnp.arange(m, dtype=jnp.int32)
-            )
-            grads = jax.tree.map(lambda g: g / m, grads)
-            loss = jnp.mean(losses)
-            metrics = jax.tree.map(jnp.mean, metricses)
-
-        lr = schedule(step_idx)
-        new_params, new_opt = opt.update(params, grads, opt_state, lr,
-                                         weight_decay=tcfg.weight_decay)
-        return new_params, new_opt, loss, metrics
-
-    return step, opt
-
-
-def opt_state_shardings(opt_shapes: OptState, param_shardings, mesh):
-    """Shardings for optimizer state: moments mirror the params."""
-    rep = NamedSharding(mesh, P())
-
-    def mirror(moment):
-        if moment == () or moment is None:
-            return ()
-        return param_shardings
-
-    return OptState(step=rep, mu=mirror(opt_shapes.mu), nu=mirror(opt_shapes.nu))
-
-
 @dataclasses.dataclass
 class Trainer:
     """End-to-end training driver (used by examples/ on small models)."""
@@ -555,14 +386,8 @@ class Trainer:
             step_fn, self.opt = build_train_step(self.cfg, self.tcfg, self.mesh, self.specs)
             self.opt_state = self.opt.init(self.params)
             bspec = batch_pspec(self.mesh)
-            # engine steps are self-dispatching (they compose cached compiled
-            # programs; re-jitting would inline and re-fuse across the
-            # program boundary their substrate parity relies on)
-            self._jit_step = (
-                step_fn
-                if getattr(step_fn, "self_dispatching", False)
-                else jax.jit(step_fn, donate_argnums=(0, 1))
-            )
+            # the step dispatches its own compiled programs: never re-jit it
+            self._jit_step = step_fn
             self._bsharding = NamedSharding(self.mesh, bspec)
             self.step = 0
 

@@ -5,8 +5,7 @@ Memory discipline: full-sequence attention never materializes the
 online-softmax over KV chunks (lax.scan) nested inside a q-chunk map
 (lax.map), so the transient per chip is ``O(B * q_chunk * H * kv_chunk)``.
 This is the pure-jnp flash pattern — on TPU the same tiling would live in a
-Pallas kernel; here the model code stays backend-portable and the dry-run
-memory analysis reflects the tiled footprint.
+Pallas kernel; here the model code stays backend-portable.
 
 Sliding-window attention is mask-based in training/prefill and
 ring-buffer-based in decode (the cache holds only ``window`` entries), which
@@ -21,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.protomath import pmm
 from repro.models.layers import apply_rope
 from repro.models.module import dense_param, split_tree
 
@@ -247,10 +245,10 @@ def multihead_attention(
     Returns (output (B,S,Dm), k, v) — k/v returned so prefill can seed a cache.
     """
     g = n_heads // n_kv_heads
-    q = pmm("bsd,dhk->bshk", x, params["wq"], w_spec=("fsdp", "tp", None))
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     kv_src = x if kv_override is None else kv_override
-    k = pmm("bsd,dhk->bshk", kv_src, params["wk"], w_spec=("fsdp", "tp", None))
-    v = pmm("bsd,dhk->bshk", kv_src, params["wv"], w_spec=("fsdp", "tp", None))
+    k = jnp.einsum("bsd,dhk->bshk", kv_src, params["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", kv_src, params["wv"])
     kpos = positions if kv_positions is None else kv_positions
     if rope_theta is not None:
         q = apply_rope(q, positions, rope_theta)
@@ -276,7 +274,7 @@ def multihead_attention(
         else:
             out = _flash_attention(qg, k, v, positions, kpos, causal, window)
     out = out.reshape(b, sq, n_heads, q.shape[-1])
-    return pmm("bshk,hkd->bsd", out, params["wo"], w_spec=("tp", None, "fsdp")), k, v
+    return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), k, v
 
 
 @dataclasses.dataclass(frozen=True)

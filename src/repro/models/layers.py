@@ -1,15 +1,9 @@
-"""Shared layers: norms, embeddings, RoPE, SwiGLU MLP.
-
-All parameter consumptions go through repro.core.protomath (pmm / plookup /
-pscale / pbias) so the LAD gradient exchange covers every trainable tensor;
-with no active protocol context these are plain einsum / take / arithmetic.
-"""
+"""Shared layers: norms, embeddings, RoPE, SwiGLU MLP."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.protomath import plookup, pmm, pscale
 from repro.models.module import dense_param, scale_param, split_tree
 
 
@@ -24,7 +18,7 @@ def rmsnorm(params, x, eps: float = 1e-5):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps)
-    return pscale(out, params["scale"]).astype(x.dtype)
+    return (out * params["scale"]).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -37,12 +31,12 @@ def embedding_init(key, vocab: int, d_model: int, dtype):
 
 
 def embed(params, tokens):
-    return plookup(params["table"], tokens, w_spec=("tp", "fsdp"))
+    return jnp.take(params["table"], tokens, axis=0)
 
 
 def unembed(params, x):
     """Logits via the (tied or untied) embedding table: (..., d) @ (V, d)^T."""
-    return pmm("...d,vd->...v", x, params["table"], w_spec=("tp", "fsdp")).astype(jnp.float32)
+    return jnp.einsum("...d,vd->...v", x, params["table"]).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -91,7 +85,7 @@ def mlp_init(key, d_model: int, d_ff: int, dtype):
 
 
 def mlp(params, x):
-    gate = pmm("bsd,df->bsf", x, params["w_gate"], w_spec=("fsdp", "tp"))
-    up = pmm("bsd,df->bsf", x, params["w_up"], w_spec=("fsdp", "tp"))
+    gate = jnp.einsum("bsd,df->bsf", x, params["w_gate"])
+    up = jnp.einsum("bsd,df->bsf", x, params["w_up"])
     act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    return pmm("bsf,fd->bsd", act, params["w_down"], w_spec=("tp", "fsdp"))
+    return jnp.einsum("bsf,fd->bsd", act, params["w_down"])

@@ -9,11 +9,6 @@ gated by SiLU(z) and projected out.  Training/prefill runs a lax.scan over
 the sequence (TPU-wise this is where a fused selective-scan kernel would go;
 the recurrence is kept in fp32).  Decode is the single-step update with the
 (conv window, h) state carried in the cache.
-
-Protocol coverage: projections via pmm, biases/taps via pbias/pscale.  ``A``
-(a_log) is consumed inside the sequence scan, so it goes through
-``block_tap`` — one robust exchange for its whole accumulated cotangent
-instead of one per token (see core.protomath).
 """
 from __future__ import annotations
 
@@ -22,7 +17,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.protomath import block_tap, pbias, pmm, pscale
 from repro.models.module import dense_param, scale_param, split_tree, zeros_param
 
 
@@ -67,18 +61,18 @@ def _causal_depthwise_conv(xz: jax.Array, w: jax.Array, b: jax.Array) -> jax.Arr
     pad = jnp.pad(xz, ((0, 0), (k - 1, 0), (0, 0)))
     out = jnp.zeros_like(xz)
     for i in range(k):  # K is tiny (4): unrolled taps beat a conv op here
-        out = out + pscale(pad[:, i : i + xz.shape[1], :], w[i])
-    return pbias(out, b)
+        out = out + pad[:, i : i + xz.shape[1], :] * w[i]
+    return out + b
 
 
 def _selection(params, x_in: jax.Array, d_state: int, spec_prefix: str):
     """Input-dependent Δ (fp32, softplus), B, C.  x_in: (..., d_inner)."""
     dt_rank = params["dt_proj"].shape[0]
-    proj = pmm(f"{spec_prefix}i,ir->{spec_prefix}r", x_in, params["x_proj"], w_spec=("tp", None))
+    proj = jnp.einsum(f"{spec_prefix}i,ir->{spec_prefix}r", x_in, params["x_proj"])
     dt_raw, b_sel, c_sel = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
-    dt = pmm(f"{spec_prefix}r,ri->{spec_prefix}i", dt_raw, params["dt_proj"],
-             w_spec=(None, "tp")).astype(jnp.float32)
-    dt = jax.nn.softplus(pbias(dt, params["dt_bias"]))
+    dt = jnp.einsum(f"{spec_prefix}r,ri->{spec_prefix}i", dt_raw,
+                    params["dt_proj"]).astype(jnp.float32)
+    dt = jax.nn.softplus(dt + params["dt_bias"])
     return dt, b_sel.astype(jnp.float32), c_sel.astype(jnp.float32)
 
 
@@ -86,20 +80,17 @@ def mamba(params, x: jax.Array, d_state: int, return_state: bool = False):
     """Full-sequence selective scan.  x: (B, S, D) -> (B, S, D)[, MambaState]."""
     b, s, _ = x.shape
     d_conv = params["conv_w"].shape[0]
-    xz = pmm("bsd,di->bsi", x, params["in_proj"], w_spec=("fsdp", "tp"))
+    xz = jnp.einsum("bsd,di->bsi", x, params["in_proj"])
     x_raw, z = jnp.split(xz, 2, axis=-1)
     x_in = _causal_depthwise_conv(x_raw, params["conv_w"], params["conv_b"])
     x_in = jax.nn.silu(x_in.astype(jnp.float32)).astype(x.dtype)
 
     dt, b_sel, c_sel = _selection(params, x_in, d_state, "bs")
-    a_b, nb = block_tap(-jnp.exp(params["a_log"]))  # (nb, di, ds)
-    if b % nb != 0:
-        a_b, nb = a_b[:1], 1
-    bb = b // nb  # rows per device block
+    a_b = (-jnp.exp(params["a_log"]))[None]  # (1, di, ds)
 
     def step(h, inputs):
         dt_t, b_t, c_t, x_t = inputs  # (B,di),(B,ds),(B,ds),(B,di)
-        dt_r = dt_t.reshape(nb, bb, -1)
+        dt_r = dt_t.reshape(1, b, -1)
         decay = jnp.exp(dt_r[..., None] * a_b[:, None]).reshape(h.shape[0], -1, d_state)
         h = decay * h + (dt_t * x_t.astype(jnp.float32))[..., None] * b_t[:, None, :]
         y = jnp.einsum("bis,bs->bi", h, c_t)
@@ -113,9 +104,9 @@ def mamba(params, x: jax.Array, d_state: int, return_state: bool = False):
         x_in.transpose(1, 0, 2),
     )
     h_fin, ys = jax.lax.scan(step, h0, xs)  # (S, B, di)
-    y = ys.transpose(1, 0, 2) + pscale(x_in.astype(jnp.float32), params["d_skip"])
+    y = ys.transpose(1, 0, 2) + x_in.astype(jnp.float32) * params["d_skip"]
     y = y.astype(x.dtype) * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    out = pmm("bsi,id->bsd", y, params["out_proj"], w_spec=("tp", "fsdp"))
+    out = jnp.einsum("bsi,id->bsd", y, params["out_proj"])
     if not return_state:
         return out
     tail = x_raw[:, -(d_conv - 1):, :] if s >= d_conv - 1 else jnp.pad(

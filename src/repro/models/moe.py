@@ -1,26 +1,17 @@
-"""Mixture-of-Experts MLP with top-k routing, LAD-device-blocked dispatch.
+"""Mixture-of-Experts MLP with top-k routing and capacity-bounded dispatch.
 
-Routing and dispatch run **per logical LAD device block** (the leading
-``n`` axis of the token batch, sharded over the data mesh axes): each block
-routes its own tokens into a per-block ``(E, C, D)`` capacity buffer via
-gather, the grouped SwiGLU einsums carry the explicit ``n`` axis
-(``pre_blocked`` pmm — the expert-weight cotangent keeps per-device blocks
-for the robust exchange), and results scatter-add back per block.
-
-Experts are sharded on the ``model`` mesh axis; the cross-shard token
-movement of expert parallelism appears at the gather/scatter of the
-data-sharded token buffers against model-sharded expert weights — visible as
-all-to-all / all-gather in the dry-run HLO.
-
-Tokens beyond the per-block capacity are dropped (Switch-style).  The router
-aux (load-balance) loss is ``n_e * sum_e f_e p_e`` per block, averaged.
+The batch's tokens route as one group (the leading ``n`` axis of size 1):
+each expert takes its top-``C`` tokens by gate weight into an ``(E, C, D)``
+capacity buffer via gather, the grouped SwiGLU einsums run per expert, and
+results scatter-add back to their tokens.  Tokens beyond an expert's capacity
+are dropped (Switch-style).  The router aux (load-balance) loss is
+``n_e * sum_e f_e p_e``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.protomath import current_protocol, pmm
 from repro.models.module import dense_param, split_tree
 
 
@@ -42,23 +33,15 @@ def expert_capacity(n_tokens: int, n_experts: int, top_k: int, factor: float = 1
     return min(c, n_tokens)
 
 
-def _n_blocks() -> int:
-    ctx = current_protocol()
-    return ctx[0].n_devices if ctx else 1
-
-
 def moe(params, x, *, top_k: int, aux_coef: float = 0.01, capacity_factor: float = 1.25):
     """x: (B, S, D) -> (out (B, S, D), aux_loss scalar fp32)."""
     b, s, d = x.shape
     n_experts = params["router"].shape[1]
-    nb = _n_blocks()
-    if b % nb != 0:
-        nb = 1
-    t = (b // nb) * s  # tokens per block
+    nb = 1  # routing groups
+    t = b * s  # tokens per group
     xb = x.reshape(nb, t, d)
 
-    logits = pmm("ntd,de->nte", xb.astype(jnp.float32), params["router"],
-                 w_spec=("fsdp", None), pre_blocked=True)
+    logits = jnp.einsum("ntd,de->nte", xb.astype(jnp.float32), params["router"])
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, idx = jax.lax.top_k(probs, top_k)  # (n, T, k)
     gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
@@ -69,23 +52,23 @@ def moe(params, x, *, top_k: int, aux_coef: float = 0.01, capacity_factor: float
     )
 
     cap = expert_capacity(t, n_experts, top_k, capacity_factor)
-    # per-block, per-expert top-C tokens by gate weight
+    # per-expert top-C tokens by gate weight
     weights_ec, token_idx = jax.lax.top_k(combine.swapaxes(1, 2), cap)  # (n, E, C)
 
     x_ec = jnp.take_along_axis(
         xb[:, None, :, :], token_idx[..., None], axis=2
     )  # (n, E, C, D) gather dispatch
-    gate = pmm("necd,edf->necf", x_ec, params["w_gate"], w_spec=("tp", "fsdp", None), pre_blocked=True)
-    up = pmm("necd,edf->necf", x_ec, params["w_up"], w_spec=("tp", "fsdp", None), pre_blocked=True)
+    gate = jnp.einsum("necd,edf->necf", x_ec, params["w_gate"])
+    up = jnp.einsum("necd,edf->necf", x_ec, params["w_up"])
     act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    y_ec = pmm("necf,efd->necd", act, params["w_down"], w_spec=("tp", None, "fsdp"), pre_blocked=True)
+    y_ec = jnp.einsum("necf,efd->necd", act, params["w_down"])
 
     y = jnp.zeros((nb, t, d), dtype=jnp.float32)
     contrib = (y_ec * weights_ec[..., None].astype(y_ec.dtype)).astype(jnp.float32)
     n_idx = jnp.arange(nb)[:, None, None]
     y = y.at[n_idx, token_idx, :].add(contrib)
 
-    # load-balance aux loss (per block, averaged)
+    # load-balance aux loss
     token_frac = jnp.mean(
         jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.float32), axis=2), axis=1
     )  # (n, E)

@@ -8,8 +8,8 @@ bonus ``u`` for the current token, and the per-head matrix-valued WKV state
     S_t = diag(w_t) S_{t-1} + k_t^T v_t
     y_t = r_t @ S_{t-1} + (sum_i r_i u_i k_i) * v_t
 
-(the u-bonus readout factored so that ``u``'s consumption happens outside the
-sequence scan — one pmm instead of a per-token exchange).  Training/prefill
+(the u-bonus readout factored so that ``u`` is consumed outside the
+sequence scan).  Training/prefill
 is a lax.scan over the sequence carrying ``S``; decode is the single-step
 update.  GroupNorm over heads on the readout, gated by SiLU(g).
 
@@ -22,7 +22,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.protomath import pbias, pmm, pscale
 from repro.models.module import dense_param, scale_param, split_tree, zeros_param
 
 
@@ -86,21 +85,21 @@ def _token_shift(x: jax.Array, x_prev_first: jax.Array | None = None) -> jax.Arr
 
 
 def _mix(x, x_shift, mu_row):
-    """lerp(x, x_shift, mu) with a protocol-aware scale on the delta."""
+    """lerp(x, x_shift, mu)."""
     delta = (x_shift - x).astype(jnp.float32)
-    return x + pscale(delta, mu_row).astype(x.dtype)
+    return x + (delta * mu_row).astype(x.dtype)
 
 
 def _projections(params, x, x_shift, head_dim: int):
     mu = params["mu"]
-    r = pmm("bsd,de->bse", _mix(x, x_shift, mu[0]), params["wr"], w_spec=("fsdp", "tp"))
-    k = pmm("bsd,de->bse", _mix(x, x_shift, mu[1]), params["wk"], w_spec=("fsdp", "tp"))
-    v = pmm("bsd,de->bse", _mix(x, x_shift, mu[2]), params["wv"], w_spec=("fsdp", "tp"))
-    g = pmm("bsd,de->bse", _mix(x, x_shift, mu[3]), params["wg"], w_spec=("fsdp", "tp"))
+    r = jnp.einsum("bsd,de->bse", _mix(x, x_shift, mu[0]), params["wr"])
+    k = jnp.einsum("bsd,de->bse", _mix(x, x_shift, mu[1]), params["wk"])
+    v = jnp.einsum("bsd,de->bse", _mix(x, x_shift, mu[2]), params["wv"])
+    g = jnp.einsum("bsd,de->bse", _mix(x, x_shift, mu[3]), params["wg"])
     xw = _mix(x, x_shift, mu[4])
-    lora_h = jnp.tanh(pmm("bsd,dr->bsr", xw, params["w_lora_a"], w_spec=("fsdp", None)))
-    lora = pmm("bsr,re->bse", lora_h, params["w_lora_b"], w_spec=(None, "tp")).astype(jnp.float32)
-    w = jnp.exp(-jnp.exp(pbias(lora, params["w0"])))  # (B,S,D) in (0,1)
+    lora_h = jnp.tanh(jnp.einsum("bsd,dr->bsr", xw, params["w_lora_a"]))
+    lora = jnp.einsum("bsr,re->bse", lora_h, params["w_lora_b"]).astype(jnp.float32)
+    w = jnp.exp(-jnp.exp(lora + params["w0"]))  # (B,S,D) in (0,1)
     b, s, d = x.shape
     h = d // head_dim
     heads = lambda t: t.reshape(b, s, h, head_dim)
@@ -114,7 +113,7 @@ def _group_norm(y: jax.Array, scale: jax.Array, head_dim: int, eps: float = 64e-
     var = jnp.var(yf, axis=-1, keepdims=True)
     normed = (yf - mean) * jax.lax.rsqrt(var + eps)
     b, s, h, hd = y.shape
-    return pscale(normed.reshape(b, s, h * hd), scale)
+    return normed.reshape(b, s, h * hd) * scale
 
 
 def rwkv_time_mix(params, x: jax.Array, head_dim: int, state: RWKVState | None = None):
@@ -124,7 +123,7 @@ def rwkv_time_mix(params, x: jax.Array, head_dim: int, state: RWKVState | None =
     r, k, v, g, w = _projections(params, x, x_shift, head_dim)
     # u-bonus readout, factored outside the scan: s_u = sum_i r_i u_i k_i
     ru_k = (r * k).astype(jnp.float32)  # (B,S,H,hd)
-    s_u = jnp.sum(pscale(ru_k, params["bonus_u"]), axis=-1)  # (B,S,H)
+    s_u = jnp.sum(ru_k * params["bonus_u"], axis=-1)  # (B,S,H)
 
     def step(s_wkv, inputs):
         r_t, k_t, v_t, w_t, su_t = inputs  # (B,H,hd) x4, (B,H)
@@ -144,7 +143,7 @@ def rwkv_time_mix(params, x: jax.Array, head_dim: int, state: RWKVState | None =
     y = ys.transpose(1, 0, 2, 3)  # (B, S, H, hd)
     y = _group_norm(y, params["ln_scale"], head_dim).astype(x.dtype)
     y = y * jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)
-    out = pmm("bsd,de->bse", y, params["wo"], w_spec=("tp", "fsdp")).astype(x.dtype)
+    out = jnp.einsum("bsd,de->bse", y, params["wo"]).astype(x.dtype)
     return out, s_fin, x[:, -1, :]
 
 
@@ -152,8 +151,8 @@ def rwkv_channel_mix(params, x: jax.Array, state_prev: jax.Array | None = None):
     """RWKV squared-ReLU FFN with token shift.  x: (B, S, D)."""
     mu = params["mu"]
     x_shift = _token_shift(x, state_prev)
-    k = pmm("bsd,df->bsf", _mix(x, x_shift, mu[0]), params["wk"], w_spec=("fsdp", "tp"))
+    k = jnp.einsum("bsd,df->bsf", _mix(x, x_shift, mu[0]), params["wk"])
     k = jnp.square(jax.nn.relu(k.astype(jnp.float32))).astype(x.dtype)
-    r_in = pmm("bsd,de->bse", _mix(x, x_shift, mu[1]), params["wr"], w_spec=("fsdp", "tp"))
+    r_in = jnp.einsum("bsd,de->bse", _mix(x, x_shift, mu[1]), params["wr"])
     r = jax.nn.sigmoid(r_in.astype(jnp.float32)).astype(x.dtype)
-    return r * pmm("bsf,fd->bsd", k, params["wv"], w_spec=("tp", "fsdp")), x[:, -1, :]
+    return r * jnp.einsum("bsf,fd->bsd", k, params["wv"]), x[:, -1, :]

@@ -61,8 +61,8 @@ def init_decode_state(cfg: ArchConfig, batch: int, seq_len: int, filled: int | N
     """Full decode state: per-block caches stacked over periods, plus an
     explicit ``"pos"`` counter (absolute position of the next token).
 
-    ``filled`` — number of tokens already in the cache (dry-run decode shapes
-    use ``seq_len`` per the assignment: one new token against a full cache).
+    ``filled`` — number of tokens already in the cache (default ``seq_len``: one new
+    token against a full cache).
     ``"pos"`` is the position source of truth for decode-time position
     embeddings: block caches are not reliable here (a cross-attention or
     recurrent first block never advances a ``length``).

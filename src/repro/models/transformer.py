@@ -9,11 +9,6 @@ Block kinds (see configs.base.BlockSpec): ``attn`` (causal GQA + RoPE),
 ``attn_nope`` (no RoPE — whisper; causal unless encoder-side), ``mamba``,
 ``rwkv``, ``cross`` (cross-attention to frontend/encoder tokens).
 MLP flavors: ``dense`` (SwiGLU), ``moe``, ``rwkv_ffn``, ``none``.
-
-The LAD protocol needs no plumbing here: every parameter consumption in the
-layer library goes through repro.core.protomath, which picks up the active
-protocol context installed by the train step (launch/train.py).  Without a
-context this is a plain pjit/GSPMD model.
 """
 from __future__ import annotations
 
@@ -23,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, BlockSpec
-from repro.core.protomath import plookup, pmm
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import mamba as mamba_lib
@@ -197,8 +191,7 @@ def _block_apply(cfg, spec: BlockSpec, bp, x, positions, cross_src):
 
 def _encode_frontend(params, cfg: ArchConfig, frontend):
     """Project stubbed frontend embeddings; run the whisper encoder stack."""
-    src = pmm("bsf,fd->bsd", frontend.astype(cfg.dtype), params["frontend_proj"],
-              w_spec=(None, "fsdp"))
+    src = jnp.einsum("bsf,fd->bsd", frontend.astype(cfg.dtype), params["frontend_proj"])
     if cfg.family == "audio" and cfg.encoder.n_encoder_layers > 0:
         src = src + L.sinusoidal_positions(src.shape[1], cfg.d_model)[None].astype(src.dtype)
         enc_spec = BlockSpec(mixer="attn_nope", mlp="dense")
@@ -275,7 +268,7 @@ def forward(
         params, specs, cfg, tokens, frontend=frontend, remat=remat
     )
     head = _unembed_table(params, cfg, emb_table)
-    logits = pmm("bsd,vd->bsv", x, head, w_spec=("tp", "fsdp"))
+    logits = jnp.einsum("bsd,vd->bsv", x, head)
     return logits.astype(jnp.float32), aux
 
 
@@ -299,9 +292,9 @@ def _chunked_ce(x: jax.Array, head: jax.Array, labels: jax.Array) -> jax.Array:
 
     def one(args):
         xc, lc = args  # (B, chunk, D), (B, chunk)
-        logits = pmm("bsd,vd->bsv", xc, head, w_spec=("tp", "fsdp")).astype(jnp.float32)
+        logits = jnp.einsum("bsd,vd->bsv", xc, head).astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)  # (B, chunk)
-        lab_rows = plookup(head, lc, w_spec=("tp", "fsdp")).astype(jnp.float32)  # (B, chunk, D)
+        lab_rows = jnp.take(head, lc, axis=0).astype(jnp.float32)  # (B, chunk, D)
         lab_logit = jnp.einsum("bsd,bsd->bs", xc.astype(jnp.float32), lab_rows)
         return lse - lab_logit
 

@@ -1,11 +1,8 @@
 """The one blocking wall-clock timer shared by every perf path.
 
-Three different timing idioms had grown in the tree: ``kernel_bench._time``
-(perf_counter, blocks every iteration), the figure drivers' inline
-perf_counter loops, and ``launch/dryrun.py`` timing compiles with
-``time.time()`` — which is NON-monotonic (NTP slew / clock steps can make a
-compile appear negative or minutes long).  This module is the single
-implementation: monotonic ``time.perf_counter``, and for device work a
+This module is the single implementation of a wall-clock timing loop:
+monotonic ``time.perf_counter`` (``time.time()`` is NON-monotonic — NTP slew
+or clock steps can make an interval appear negative), and for device work a
 ``jax.block_until_ready`` on EVERY iteration — async dispatch otherwise lets
 the loop enqueue without finishing, timing only the final drain.
 

@@ -2,7 +2,7 @@
 
 NOTE: no device-count XLA_FLAGS here — smoke tests and benches must see the
 1 real CPU device.  Tests that need a small virtual mesh spawn a subprocess
-(see tests/test_distributed.py) or run single-device shard_map.
+(see tests/test_stage_scopes.py) or run single-device shard_map.
 
 The suite is jit-compile bound (~140 tests, each compiling small programs),
 so we trim LLVM's expensive passes for test runs: correctness is unchanged,

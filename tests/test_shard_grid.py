@@ -22,7 +22,7 @@ from repro.launch.mesh import padded_lane_count
 from repro.testing import given, settings, strategies as st
 
 STEPS, DIM = 5, 12
-SHARDS = ("shard_map", "pmap")
+SHARDS = ("shard_map",)
 
 
 def _match(got, ref):
@@ -41,7 +41,7 @@ def _match(got, ref):
 
 @pytest.mark.parametrize("n", (10, 16, 32))
 def test_sharded_bitwise_vs_unsharded_and_standalone(n):
-    """Both shard modes must reproduce every lane of the unsharded grid
+    """The sharded grid must reproduce every lane of the unsharded grid
     BITWISE, and the grid itself its standalone per-scenario trajectories —
     with a lane count (5) not divisible by any multi-device count, so the
     device-padding path is always exercised."""
@@ -256,8 +256,10 @@ def test_padded_lane_count_rejects_empty_axis():
 
 def test_shard_validation():
     rows = scenarios.synthetic_sweep(2, n_devices=10, n_byz=2)
-    with pytest.raises(ValueError, match="shard"):
-        scenarios.run_grid(rows, 2, dim=DIM, shard="gspmd")
+    # "none" and "shard_map" are the only substrates
+    for shard in ("gspmd", "pmap"):
+        with pytest.raises(ValueError, match="unknown shard mode"):
+            scenarios.run_grid(rows, 2, dim=DIM, shard=shard)
     with pytest.raises(ValueError, match="max_lanes_per_device"):
         scenarios.run_grid(rows, 2, dim=DIM, max_lanes_per_device=0)
     # the per-scenario reference modes must refuse (not silently drop) the

@@ -1,9 +1,9 @@
-"""The protocol-engine LM train path (launch/train.py, protocol_impl="engine").
+"""The protocol-engine LM train path (launch/train.py).
 
 The transformer LM trains through core.byzantine.protocol_round — the same
 assignment -> eq.-(5) encode -> compress -> attack -> robust-aggregate
 pipeline as the Section-VII linear-regression runs — on the default
-single-CPU-device mesh (no subprocess, unlike the protomath mesh tests).
+single-CPU-device mesh (no subprocess).
 """
 import jax
 import numpy as np
@@ -54,6 +54,23 @@ def test_lm_trains_through_protocol_engine():
     losses = [l for _, l in hist]
     assert all(np.isfinite(losses)), losses
     assert losses[-1] < losses[0], losses
+
+
+def test_honest_mean_trains_and_lad_beats_the_mean_under_attack():
+    """The honest mean (protocol "none") trains; under the same sign-flip
+    attack (2 of 8 subsets) LAD + CWTM ends below the plain mean."""
+    cfg = _tiny_cfg()
+    base = dict(arch=cfg.name, n_subsets=N_SUB, optimizer="adamw", lr=3e-3, steps=8)
+
+    def losses(**kw):
+        return [l for _, l in _run(TrainConfig(**base, **kw), cfg, base["steps"])]
+
+    honest = losses(protocol="none")
+    assert honest[-1] < honest[0] - 0.3, honest
+    attack = dict(n_byz=2, attack="sign_flip")
+    lad = losses(protocol="lad", d=2, aggregator="cwtm", trim_frac=0.25, **attack)
+    mean = losses(protocol="plain", aggregator="mean", **attack)
+    assert lad[-1] < mean[-1], (lad, mean)
 
 
 @pytest.mark.slow

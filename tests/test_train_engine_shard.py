@@ -1,7 +1,7 @@
 """Sharded LM-engine train path: the cross-substrate conformance suite.
 
-The tentpole claim under test: ``launch/train.py::build_engine_step`` with
-``TrainConfig.shard="pmap"|"shard_map"`` produces BITWISE-identical training
+The claim under test: ``launch/train.py::build_train_step`` with
+``TrainConfig.shard="shard_map"`` produces BITWISE-identical training
 steps to ``shard="none"`` — parameters, optimizer state, loss and metrics —
 at the clean simulation scales of the engine guarantee (N = 10/16/32, see
 README "Engine guarantees" and repro/numerics.py), and the LM-scale scenario
@@ -29,7 +29,7 @@ from repro.launch import train as train_lib
 from repro.launch.mesh import make_host_mesh
 
 CLEAN_SCALES = (10, 16, 32)
-SHARDS = ("shard_map", "pmap")
+SHARDS = ("shard_map",)
 STEPS = 2
 
 
@@ -88,8 +88,8 @@ def _assert_trees_equal(got, ref, label):
 )
 def test_sharded_step_bitwise_vs_unsharded(n):
     """Params, optimizer state, loss and metrics after LAD+CWTM engine steps
-    must be bitwise identical between shard="none" and both device
-    substrates, at every clean scale."""
+    must be bitwise identical between shard="none" and the sharded
+    substrate, at every clean scale."""
     ref = _run_steps(_tcfg(n, "none"))
     for shard in SHARDS:
         _assert_trees_equal(_run_steps(_tcfg(n, shard)), ref, f"N={n} {shard}")
@@ -100,8 +100,6 @@ def test_sharded_step_bitwise_microbatched_com_lad():
     with Com-LAD compression keeps the substrate parity bitwise."""
     kw = dict(compression="rand_sparse", q_hat_frac=0.5, microbatches=2)
     ref = _run_steps(_tcfg(10, "none", **kw))
-    # shard_map only (test-speed budget): pmap parity at every clean scale
-    # is held by the uncompressed step tests above
     _assert_trees_equal(
         _run_steps(_tcfg(10, "shard_map", **kw)), ref, "micro com-lad shard_map"
     )
@@ -139,9 +137,7 @@ def test_lm_grid_sharded_bitwise_vs_unsharded_and_standalone():
     """The LM-scale scenario grid: sharded == unsharded == standalone
     per-scenario trajectories, bitwise, lanes and metrics — with a lane
     count (3) not divisible by any multi-device count so the padding path is
-    always exercised.  Only the shard_map substrate runs here (test-speed
-    budget); pmap parity is held by the step tests above at every clean
-    scale and by the slow full-matrix test below.  Slow-marked: every push
+    always exercised.  Slow-marked: every push
     still asserts the sharded-LM-grid bitwise + zero-compile contract via
     the CI determinism job's standalone ``scripts/bench_smoke.py``
     (``smoke_lm_engine``); this finer-grained version runs nightly."""
@@ -185,8 +181,8 @@ def test_lm_grid_sharded_bitwise_vs_unsharded_and_standalone():
 @pytest.mark.slow
 def test_lm_grid_full_matrix_sharded_bitwise():
     """The full default lm_sweep matrix (method x attack x compressor, 12
-    rows / 4 compile buckets) at a second clean scale, across both
-    substrates — the nightly --runslow version of the fast 3-row test."""
+    rows / 4 compile buckets) at a second clean scale, sharded and chunked —
+    the nightly --runslow version of the fast 3-row test."""
     rows = scenarios.lm_sweep(n_devices=16, n_byz=3)
     assert len(rows) == 12
     assert len({scenarios._bucket_signature(s) for s in rows}) == 4
@@ -206,10 +202,10 @@ def test_lm_grid_full_matrix_sharded_bitwise():
 
 def test_trainer_drives_sharded_substrates_identically():
     """End-to-end through ``Trainer`` (which commits params/batches to its
-    own 1x1 GSPMD mesh — the integration the direct step calls skip): every
-    substrate must produce the identical loss history.  Trainer must not
-    re-jit the self-dispatching engine step, and the sharded step must
-    re-lay-out the mesh-committed inputs onto the engine mesh itself."""
+    own 1x1 GSPMD mesh — the integration the direct step calls skip): both
+    substrates must produce the identical loss history.  Trainer must not
+    re-jit the engine step, and the sharded step must re-lay-out the
+    mesh-committed inputs onto the engine mesh itself."""
     from repro.launch.train import Trainer
 
     cfg = _arch()
@@ -224,8 +220,7 @@ def test_trainer_drives_sharded_substrates_identically():
             yield {k: v.reshape(-1, v.shape[-1]) for k, v in b.items()}
 
     hists = {}
-    for shard in ("none", "shard_map"):  # pmap Trainer plumbing is identical;
-        # pmap-vs-none step parity runs at every clean scale above
+    for shard in ("none",) + SHARDS:
         tcfg = _tcfg(10, shard)  # same config as the step tests: the round
         tr = Trainer(cfg=cfg, tcfg=tcfg, mesh=make_host_mesh(1, 1))  # and
         # apply programs are already cached — this test costs only Trainer
@@ -253,18 +248,19 @@ def test_run_lm_grid_validation():
 
 def test_engine_step_shard_validation():
     """The negative paths of the sharded train step: unknown shard strings
-    and shard= on the protomath realization must raise clear ValueErrors."""
+    (``"pmap"`` among them; "none" and "shard_map" are the only substrates)
+    and any protocol realization but the engine must raise clear
+    ValueErrors."""
     cfg = _arch()
     mesh = make_host_mesh(1, 1)
-    with pytest.raises(ValueError, match="unknown engine shard mode"):
+    for shard in ("gspmd", "pmap"):
+        with pytest.raises(ValueError, match="unknown engine shard mode"):
+            train_lib.build_train_step(
+                cfg, _tcfg(8, shard), mesh, specs=None
+            )
+    with pytest.raises(ValueError, match="unknown protocol_impl 'gspmd'"):
         train_lib.build_train_step(
-            cfg, _tcfg(8, "gspmd"), mesh, specs=None
-        )
-    with pytest.raises(ValueError, match="engine-path option"):
-        train_lib.build_train_step(
-            cfg,
-            TrainConfig(protocol="lad", protocol_impl="protomath",
-                        shard="shard_map"),
+            cfg, TrainConfig(protocol="lad", protocol_impl="gspmd"),
             mesh, specs=None,
         )
     # unknown protocol_impl still wins over shard validation
